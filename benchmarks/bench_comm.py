@@ -35,13 +35,14 @@ def ps_bytes_from_hlo(workers: int, model: int, vocab: int, k: int,
         from repro.data import corpus as corpus_mod
         from repro.launch import lda as L
         from repro.analysis import hlo_stats as H
+        from repro.sharding.mesh import make_mesh
 
         corp = corpus_mod.synthetic_corpus(300, {vocab}, true_topics=8,
             mean_doc_len={max(tokens // 300, 8)}, seed=0)
         cfg = lda.LDAConfig(num_topics={k}, vocab_size={vocab},
                             block_tokens=1024, num_shards={model})
         data = {workers} // {model}
-        mesh = jax.make_mesh((data, {model}), ("data", "model"))
+        mesh = make_mesh((data, {model}), ("data", "model"))
         fn = L.make_spmd_sweep(mesh, cfg)
         shards = corpus_mod.shard_tokens(corp, {workers}, cfg.block_tokens)
         npad = max(s[0].shape[0] for s in shards)

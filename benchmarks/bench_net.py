@@ -60,7 +60,7 @@ def _run_arm(workers: int, *, epochs: int, corp, topics: int,
         base = WorkerConfig(server=srv.address, stream_dir=sdir,
                             num_topics=topics, block_tokens=block_tokens,
                             seed=0, commit_hot_rows=32, delay_ms=DELAY_MS)
-        pool = WorkerPool(srv.address, base)
+        pool = WorkerPool(srv.address, base, env={"JAX_PLATFORMS": "cpu"})
         if workers > 1:
             pool.add_worker(slow_ms=STRAGGLER_SLOW_MS)   # the straggler
             pool.start(workers - 1)

@@ -19,8 +19,9 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 
 if __name__ == "__main__":
-    # device count must be set before jax initialises -> exec the launcher
-    # in a fresh interpreter (this is what a multi-host launcher does too)
+    # eight forced host devices on the CPU backend; the device count must
+    # be set before jax initialises -> exec the launcher in a fresh
+    # interpreter (this is what a multi-host launcher does too)
     cmd = [sys.executable, "-m", "repro.launch.lda",
            "--devices", "8", "--mesh-model", "2",
            "--docs", "600", "--vocab", "1500", "-k", "30",
@@ -28,5 +29,6 @@ if __name__ == "__main__":
            # hybrid push route: hottest 200 words dense, cold tail as
            # coordinate deltas (paper section 3.3)
            "--staleness", "2", "--hot-words", "200"]
-    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
+    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"),
+               JAX_PLATFORMS="cpu")
     raise SystemExit(subprocess.call(cmd, env=env, cwd=ROOT))

@@ -134,6 +134,7 @@ class EvalCallback(Callback):
         self.every = int(every)
         self.include_last = include_last
         self.heldout = heldout
+        self._packed = None       # the held-out split, on the device once
         self.coherence = coherence
         self.log_fn = log_fn
         self.history: list = []
@@ -174,11 +175,12 @@ class EvalCallback(Callback):
         phi = ppl.phi_from_counts(
             view.nwk.to_dense().astype(jnp.float32),
             view.nk.pull_all().result().astype(jnp.float32), cfg.beta)
-        w, d, fold, ev = corpus_mod.fold_eval_split(self.heldout)
-        w, d = jnp.asarray(w), jnp.asarray(d)
-        return float(ppl.heldout_perplexity(
-            w, d, jnp.asarray(fold), w, d, jnp.asarray(ev), phi,
-            self.heldout.num_docs, cfg.alpha))
+        if self._packed is None:
+            self._packed = tuple(map(jnp.asarray,
+                                     corpus_mod.packed_fold_eval_split(
+                                         self.heldout)))
+        return float(ppl.heldout_perplexity_packed(*self._packed, phi,
+                                                   cfg.alpha))
 
     def _coherence(self, view: SweepView) -> float:
         import jax.numpy as jnp

@@ -48,7 +48,7 @@ from repro.api.job import NET, SPMD, JobValidationError, LDAJob
 from repro.core import lightlda as lda
 from repro.core import perplexity as ppl
 from repro.data import stream as stream_mod
-from repro.sharding.compat import shard_map
+from repro.sharding.mesh import make_mesh
 from repro.train import async_exec
 from repro.train import checkpoint as ckpt
 
@@ -57,7 +57,9 @@ class SessionResult(NamedTuple):
     """What a finished run hands back.
 
     ``nwk``/``nk`` are the final PS handles (always present); ``state``
-    is the full ``SamplerState`` for in-memory in-process runs; ``reader``
+    is the full ``SamplerState`` for in-memory runs (for the SPMD plane,
+    the global view: every worker's tokens flattened, doc ids offset per
+    worker); ``reader``
     the stream reader for streamed runs (its z files hold the
     assignments).  ``history`` is the eval callback's rows, ``info`` the
     executor's realised-schedule description.
@@ -150,7 +152,7 @@ def make_spmd_sweep(mesh, cfg: "lda.LDAConfig", staleness: int = 0,
         return (out.z[None], out.ndk[None], out.nwk.value, out.nk.value)
 
     wspec = P(("data", "model"), None)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(wspec, wspec, wspec, wspec, wspec, wspec,
                   P(("data", "model"), None, None), P("model", None),
@@ -781,6 +783,14 @@ class _NetPlane:
             use_kernels=cfg.use_kernels, seed=self.seed,
             commit_hot_rows=self.exec_cfg.hot_words or 0)
         self.pool = WorkerPool(self.address, base, log_fn=self.log_fn)
+        if not self.pool.cpu_workers and jax.default_backend() != "cpu":
+            raise ValueError(
+                f"backend='net' spawns {job.workers} worker process(es), "
+                f"but this session process already holds the "
+                f"{jax.default_backend()} chip(s), and a chip belongs to "
+                f"one process.  Ask for CPU workers by name "
+                f"(JAX_PLATFORMS=cpu), or train on the chip with "
+                f"backend='in_process' or 'spmd'.")
         self.pool.start(job.workers)
         self._shard_tokens = [self.reader.shard(s, load_z=False).n_tokens
                               for s in range(meta.num_shards)]
@@ -907,12 +917,13 @@ def _resolve_mesh(cfg: "lda.LDAConfig", mesh_model: int):
     model = int(mesh_model)
     if model < 1 or n_dev % model:
         raise ValueError(
-            f"device count {n_dev} is not divisible by "
-            f"mesh_model={model}; adjust mesh_model or force host "
-            f"devices (XLA_FLAGS=--xla_force_host_platform_device_"
-            f"count=N)")
+            f"device count {n_dev} ({jax.default_backend()}) is not "
+            f"divisible by mesh_model={model}; on a TPU host pick a "
+            f"mesh_model that divides its chip count, or under "
+            f"JAX_PLATFORMS=cpu force host devices "
+            f"(XLA_FLAGS=--xla_force_host_platform_device_count=N)")
     data = n_dev // model
-    mesh = jax.make_mesh((data, model), ("data", "model"))
+    mesh = make_mesh((data, model), ("data", "model"))
     cfg = lda.LDAConfig(**{**cfg.__dict__, "num_shards": model})
     return mesh, data, model, data * model, cfg
 
@@ -988,6 +999,19 @@ class _SpmdPlane:
         return (client.wrap_matrix(self.nwk_val, self.cfg.V),
                 client.wrap_vector(self.nk_val))
 
+    def _global_state(self) -> "lda.SamplerState":
+        """Every worker's partition as one flat ``SamplerState``: worker
+        ``j``'s doc ids (and token offsets) shift by ``j`` times the
+        per-worker capacity, so ``ndk`` rows stay distinct."""
+        k, n = self.cfg.K, self.w.shape[1]
+        wk = jnp.arange(self.workers)[:, None]
+        nwk, nk = self._handles()
+        return lda.SamplerState(
+            self.w.reshape(-1), (self.d + wk * self.dmax).reshape(-1),
+            self.z.reshape(-1), self.valid.reshape(-1),
+            (self.doc_start + wk * n).reshape(-1), self.doc_len.reshape(-1),
+            nwk, nk, self.ndk.reshape(self.workers * self.dmax, k))
+
     def view(self, i: int) -> SweepView:
         nwk, nk = self._handles()
         return SweepView(self, step=i + 1, epoch=0, pos=i, shard_id=None,
@@ -1000,14 +1024,10 @@ class _SpmdPlane:
         jax.block_until_ready(self.z)
 
     def perplexity(self, view) -> float:
-        cfg = self.cfg
-        full = view.nwk.to_dense()
-        theta_like_ndk = self.ndk.reshape(self.workers * self.dmax, cfg.K)
+        cfg, st = self.cfg, self._global_state()
         return float(ppl.training_perplexity(
-            self.w.reshape(-1),
-            (self.d + jnp.arange(self.workers)[:, None] * self.dmax
-             ).reshape(-1), self.valid.reshape(-1), theta_like_ndk, full,
-            self.nk_val, cfg.alpha, cfg.beta))
+            st.w, st.d, st.valid, st.ndk, view.nwk.to_dense(), self.nk_val,
+            cfg.alpha, cfg.beta))
 
     def history_row(self, view, p: float) -> dict:
         return {"sweep": view.step, "perplexity": p,
@@ -1033,8 +1053,8 @@ class _SpmdPlane:
         pass
 
     def result(self) -> SessionResult:
-        nwk, nk = self._handles()
-        return SessionResult(nwk, nk, [], self.info, None, None)
+        st = self._global_state()
+        return SessionResult(st.nwk, st.nk, [], self.info, st, None)
 
 
 # ---------------------------------------------------------------------------

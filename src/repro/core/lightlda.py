@@ -59,7 +59,7 @@ class LDAConfig:
     num_shards: int = 1           # parameter-server shards (mesh model axis)
     use_kernels: bool = False     # Pallas kernels for MH + delta aggregation
     kernel_interpret: Optional[bool] = None  # None: kernels.ops.default_interpret
-                                  # (REPRO_INTERPRET env var / CPU autodetect)
+                                  # (interpret on CPU, compiled on a TPU)
 
     @property
     def K(self) -> int:
@@ -295,6 +295,18 @@ def make_doc_draw(key_shape, d_b, z_snapshot, doc_start, doc_len, cfg: LDAConfig
 # the -dw correction restricted to the local doc counts.
 # ---------------------------------------------------------------------------
 
+def build_alias_tables(weights: jax.Array, use_kernels: bool = False,
+                       interpret: Optional[bool] = None
+                       ) -> alias_mod.AliasTable:
+    """Alias tables for [R, K] proposal weights: the Pallas kernel under
+    ``use_kernels`` (bitwise the jnp Vose build, and on a TPU at real
+    V x K far cheaper), else ``alias.build_alias_rows``."""
+    if use_kernels:
+        from repro.kernels import ops as kops
+        return kops.alias_build(weights, interpret=interpret)
+    return alias_mod.build_alias_rows(weights)
+
+
 class FrozenModel(NamedTuple):
     """Immutable model snapshot for inference.
 
@@ -321,21 +333,15 @@ def freeze_model(nwk_dense: jax.Array, nk: jax.Array, cfg: LDAConfig,
     otherwise it is computed here.
 
     ``use_kernels`` routes the alias build through the Pallas kernel
-    (``kernels.ops.alias_build``): same induced proposal pmf, but the
-    alias *assignments* are permutation-dependent, so sampled fold-in
-    paths may differ from the jnp construction -- opt-in, matching the
-    training-side ``cfg.use_kernels`` convention.
+    (``build_alias_tables``), matching the training-side
+    ``cfg.use_kernels`` convention.
     """
     from repro.core import perplexity as ppl
     nwk_f = nwk_dense.astype(jnp.float32)
     nk_f = nk.astype(jnp.float32)
     if weights is None:
         weights = ppl.phi_from_counts(nwk_f, nk_f, cfg.beta)
-    if use_kernels:
-        from repro.kernels import ops as kops
-        table = kops.alias_build(weights, interpret=interpret)
-    else:
-        table = alias_mod.build_alias_rows(weights)
+    table = build_alias_tables(weights, use_kernels, interpret)
     return FrozenModel(nwk_f, nk_f, table.prob, table.alias)
 
 
@@ -364,8 +370,8 @@ def sample_tokens_frozen(model: FrozenModel, rng: MHRandoms, z0: jax.Array,
 
 # Dense delta aggregation (paper section 3.3) lives in ps/routes.py now:
 # a block's reassignments aggregate through the handle's PushRoute
-# (DenseRoute covers the old count_deltas; the executors add the
-# worker-local n_k/n_dk halves via train.async_exec.token_deltas).
+# (DenseRoute covers the old count_deltas; the executors merge the
+# worker-local n_k/n_dk halves with in-place scatter-adds).
 
 
 # ---------------------------------------------------------------------------

@@ -31,13 +31,35 @@ def phi_from_counts(nwk: jax.Array, nk: jax.Array, beta: float) -> jax.Array:
     return (nwk + beta) / (nk[None, :] + v * beta)
 
 
-@partial(jax.jit, static_argnames=("num_docs",))
-def log_likelihood(w: jax.Array, d: jax.Array, valid: jax.Array,
-                   theta: jax.Array, phi: jax.Array, num_docs: int) -> jax.Array:
-    """Σ_i log p(w_i | θ_{d_i}, φ) over valid tokens."""
+def _token_ll(w, d, valid, theta, phi):
     p = jnp.einsum("ik,ik->i", jnp.take(theta, d, axis=0),
                    jnp.take(phi, w, axis=0))
     return jnp.sum(jnp.where(valid, jnp.log(jnp.maximum(p, 1e-30)), 0.0))
+
+
+@partial(jax.jit, static_argnames=("num_docs", "chunk"))
+def log_likelihood(w: jax.Array, d: jax.Array, valid: jax.Array,
+                   theta: jax.Array, phi: jax.Array, num_docs: int,
+                   chunk: int = 1 << 16) -> jax.Array:
+    """Σ_i log p(w_i | θ_{d_i}, φ) over valid tokens.
+
+    Tokens are scored ``chunk`` at a time: each token reads a [K] row of
+    θ and of φ, and at a real corpus size (10^8 tokens x K=1024) the two
+    gathered [N, K] operands alone would be hundreds of GB."""
+    n = w.shape[0]
+    if n <= chunk:
+        return _token_ll(w, d, valid, theta, phi)
+    pad = (-n) % chunk
+
+    def split(x):
+        return jnp.pad(x, (0, pad)).reshape(-1, chunk)
+
+    def body(total, xs):
+        return total + _token_ll(*xs, theta, phi), None
+
+    total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32),
+                            (split(w), split(d), split(valid)))
+    return total
 
 
 @partial(jax.jit, static_argnames=("num_docs", "num_iters"))
@@ -67,6 +89,31 @@ def heldout_perplexity(fold_w, fold_d, fold_valid, eval_w, eval_d, eval_valid,
     ll = log_likelihood(eval_w, eval_d, eval_valid, theta, phi, num_docs)
     n = jnp.maximum(eval_valid.sum(), 1)
     return jnp.exp(-ll / n)
+
+
+@partial(jax.jit, static_argnames=("num_iters",))
+def heldout_perplexity_packed(w, fold, ev, phi, alpha: float,
+                              num_iters: int = 20) -> jax.Array:
+    """``heldout_perplexity`` on the [D, L] layout of
+    ``data.corpus.packed_fold_eval_split``: the same EM fold-in and
+    score, but θ reaches each token by broadcast and n_dk is a sum over
+    L, where the flat version gathers θ per token and scatter-adds n_dk
+    with ~L duplicate rows per document."""
+    k = phi.shape[1]
+    phi_rows = jnp.take(phi, w, axis=0)                      # [D, L, K]
+    wgt = fold.astype(jnp.float32)[..., None]
+
+    def body(_, ndk):
+        theta = theta_from_counts(ndk, alpha)
+        resp = theta[:, None, :] * phi_rows
+        resp = resp / jnp.maximum(resp.sum(-1, keepdims=True), 1e-30)
+        return (resp * wgt).sum(1)
+
+    ndk = jax.lax.fori_loop(0, num_iters, body,
+                            jnp.ones((w.shape[0], k), jnp.float32))
+    p = (phi_rows * theta_from_counts(ndk, alpha)[:, None, :]).sum(-1)
+    ll = jnp.sum(jnp.where(ev, jnp.log(jnp.maximum(p, 1e-30)), 0.0))
+    return jnp.exp(-ll / jnp.maximum(ev.sum(), 1))
 
 
 def training_perplexity(w, d, valid, ndk, nwk_dense, nk,

@@ -22,9 +22,9 @@ Padding contract: padded columns carry q == 1.0 exactly and are excluded
 from both stacks, so they finish as self-aliased prob-1 buckets that can
 never be emitted as an alias target.
 
-Oracle: ``repro.core.alias.build_alias_rows`` -- equality is on the
-*induced pmf* (alias assignments are permutation-dependent; the
-distribution is not).
+Oracle: ``repro.core.alias.build_alias_rows``, bitwise: both pop the
+same stacks in the same order, and a one-hot lane reduction selects one
+value exactly.
 """
 from __future__ import annotations
 
@@ -40,20 +40,19 @@ def _alias_kernel(q_ref, small_ref, large_ref, ns_ref, nl_ref,
     r, kp = q_ref.shape
     iota = jax.lax.broadcasted_iota(jnp.int32, (r, kp), 1)
 
+    # Per-row scalars (stack heights, popped indices) stay [R, 1] columns
+    # so every select broadcasts along lanes; selections reduce in f32
+    # (exact for indices < 2^24).
     def col_f(mat, idx):
         """mat[r, idx_r] per row (one-hot masked lane reduction)."""
-        return jnp.sum(jnp.where(iota == idx[:, None], mat, 0.0), axis=1)
+        return jnp.sum(jnp.where(iota == idx, mat, 0.0), axis=1,
+                       keepdims=True)
 
     def col_i(mat, idx):
-        return jnp.sum(jnp.where(iota == idx[:, None], mat, 0), axis=1)
+        return col_f(mat.astype(jnp.float32), idx).astype(jnp.int32)
 
-    def set_col_f(mat, idx, val, active):
-        hit = (iota == idx[:, None]) & active[:, None]
-        return jnp.where(hit, val[:, None], mat)
-
-    def set_col_i(mat, idx, val, active):
-        hit = (iota == idx[:, None]) & active[:, None]
-        return jnp.where(hit, val[:, None], mat)
+    def set_col(mat, idx, val, active):
+        return jnp.where((iota == idx) & active, val, mat)
 
     def body(_, state):
         q, prob, alias, small, large, ns, nl = state
@@ -63,27 +62,21 @@ def _alias_kernel(q_ref, small_ref, large_ref, ns_ref, nl_ref,
         q_s = col_f(q, s_idx)
         q_l = col_f(q, l_idx)
 
-        prob = set_col_f(prob, s_idx, q_s, active)
-        alias = set_col_i(alias, s_idx, l_idx, active)
+        prob = set_col(prob, s_idx, q_s, active)
+        alias = set_col(alias, s_idx, l_idx, active)
         q_l_new = q_l + q_s - 1.0
-        q = set_col_f(q, l_idx, q_l_new, active)
+        q = set_col(q, l_idx, q_l_new, active)
 
         ns_after = jnp.where(active, ns - 1, ns)
         demote = active & (q_l_new < 1.0)
         nl = jnp.where(demote, nl - 1, nl)
-        small = set_col_i(small, ns_after, l_idx, demote)
+        small = set_col(small, ns_after, l_idx, demote)
         ns = jnp.where(demote, ns_after + 1, ns_after)
         return (q, prob, alias, small, large, ns, nl)
 
-    q = q_ref[...]
-    small = small_ref[...]
-    large = large_ref[...]
-    ns = ns_ref[0, :]
-    nl = nl_ref[0, :]
     prob0 = jnp.ones((r, kp), jnp.float32)
-    alias0 = iota
-
-    state = (q, prob0, alias0, small, large, ns, nl)
+    state = (q_ref[...], prob0, iota, small_ref[...], large_ref[...],
+             ns_ref[...], nl_ref[...])
     state = jax.lax.fori_loop(0, 2 * num_cols, body, state)
     _, prob, alias, _, _, _, _ = state
     prob_ref[...] = jnp.clip(prob, 0.0, 1.0)
@@ -91,15 +84,16 @@ def _alias_kernel(q_ref, small_ref, large_ref, ns_ref, nl_ref,
 
 
 def alias_build_call(q, small, large, ns, nl, *, num_cols: int,
-                     tile_rows: int = 64, interpret: bool = True):
-    """q/small/large: [V, Kp]; ns/nl: [1, V].  Returns (prob, alias)."""
+                     tile_rows: int = 128, interpret: bool = True):
+    """q/small/large: [V, Kp]; ns/nl: [V, 1] stack heights.  Returns
+    (prob, alias).  ``tile_rows`` is a multiple of 8 (sublanes)."""
     v, kp = q.shape
     tr = min(tile_rows, v)
     assert v % tr == 0, (v, tr)
     grid = (v // tr,)
 
     rows = pl.BlockSpec((tr, kp), lambda i: (i, 0))
-    cnt = pl.BlockSpec((1, tr), lambda i: (0, i))
+    cnt = pl.BlockSpec((tr, 1), lambda i: (i, 0))
 
     return pl.pallas_call(
         functools.partial(_alias_kernel, num_cols=num_cols),

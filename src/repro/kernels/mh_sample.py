@@ -10,6 +10,10 @@ kernel is *pure vector compute* on VMEM-resident tiles:
                 [1, TB] assignments -- Kp is K padded to a multiple of 128
                 so the one-hot selections land on VPU lanes.
 
+The token tile TB is chosen from Kp (``token_tile``): four [TB, Kp] row
+blocks are double-buffered and the chain keeps several [TB, Kp]
+temporaries, so a fixed TB that fits at K=128 overflows VMEM at K=1024.
+
 TPU adaptation (DESIGN.md section 2): a GPU implementation would thread one
 token per lane with random gathers; on TPU every "gather a column per row"
 becomes a one-hot masked reduction over the K lane dimension, which is a
@@ -25,10 +29,30 @@ Oracle: ``repro.core.lightlda.mh_chain`` (also re-exported in ref.py).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# Elements of one [TB, Kp] row block the default tile aims for (1 MiB of
+# f32): at Kp=1024 that is TB=256, which the v5e compiler fits in its
+# default scoped VMEM with the chain's temporaries.
+_BLOCK_ELEMS = 1 << 18
+_VMEM_DEFAULT = 16 << 20
+
+
+def token_tile(kp: int) -> int:
+    """Token tile for lane-padded width ``kp``: 256 up to Kp=1024, then
+    shrinking to the 128-lane floor of the [1, TB] per-token blocks."""
+    return max(128, min(256, _BLOCK_ELEMS // kp))
+
+
+def vmem_limit(tb: int, kp: int) -> int:
+    """Scoped-VMEM request for one grid step: the double-buffered row
+    blocks and temporaries scale with TB*Kp; never below the default."""
+    return max(_VMEM_DEFAULT, 16 * tb * kp * 4 + (4 << 20))
 
 
 def _mh_kernel(z0_ref, nwk_ref, ndk_ref, nk_ref, aprob_ref, aalias_ref,
@@ -92,15 +116,17 @@ def _mh_kernel(z0_ref, nwk_ref, ndk_ref, nk_ref, aprob_ref, aalias_ref,
 def mh_sample_call(z0, nwk_rows, ndk_rows, nk, aprob, aalias,
                    u_word, u_waccept, z_doc, u_daccept, *,
                    num_topics: int, vocab_size: int, alpha: float,
-                   beta: float, mh_steps: int, tile_tokens: int = 1024,
+                   beta: float, mh_steps: int,
+                   tile_tokens: Optional[int] = None,
                    interpret: bool = True, frozen: bool = False):
     """pallas_call wrapper (see module docstring for the layout contract).
 
-    ``frozen=True`` compiles the inference-mode chain (fold-in against a
-    frozen snapshot; -dw correction on doc counts only)."""
+    ``tile_tokens`` None takes ``token_tile(Kp)``.  ``frozen=True``
+    compiles the inference-mode chain (fold-in against a frozen snapshot;
+    -dw correction on doc counts only)."""
     b = z0.shape[1]
     kp = nwk_rows.shape[1]
-    tb = min(tile_tokens, b)
+    tb = min(tile_tokens or token_tile(kp), b)
     assert b % tb == 0, (b, tb)
     grid = (b // tb,)
 
@@ -119,6 +145,8 @@ def mh_sample_call(z0, nwk_rows, ndk_rows, nk, aprob, aalias,
         in_specs=[tok1, rows, rows, full, rows, rows, rand, rand, rand, rand],
         out_specs=tok1,
         out_shape=jax.ShapeDtypeStruct((1, b), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit(tb, kp)),
         interpret=interpret,
     )(z0, nwk_rows, ndk_rows, nk, aprob, aalias,
       u_word, u_waccept, z_doc, u_daccept)

@@ -6,10 +6,12 @@ to the jnp oracles where a kernel does not exist.
 
 ``interpret`` is resolved in ONE place -- ``default_interpret()`` -- so a
 TPU run flips a single switch instead of touching every signature: every
-wrapper takes ``interpret=None`` meaning "the process default", which is
-the ``REPRO_INTERPRET`` env var when set (``0``/``false`` compiles,
-anything else interprets), else interpret-on-CPU / compiled-on-accelerator.
-Explicit ``True``/``False`` still override per call.
+wrapper takes ``interpret=None`` meaning "the process default": interpret
+on the CPU backend (tests), compiled on an accelerator.  On the CPU the
+``REPRO_INTERPRET`` env var may override it (``0``/``false`` compiles);
+on an accelerator asking for interpret mode is an error, so a chip run
+can never silently time the Pallas interpreter.  Explicit
+``True``/``False`` still override per call.
 """
 from __future__ import annotations
 
@@ -31,15 +33,19 @@ LANES = 128  # TPU lane width: K is padded to a multiple of this
 
 
 def default_interpret() -> bool:
-    """The process-wide Pallas interpret default (see module docstring).
-
-    Precedence: ``REPRO_INTERPRET`` env var, else interpret when the JAX
-    backend is CPU (kernels cannot compile there) and compile otherwise.
-    """
+    """The process-wide Pallas interpret default (see module docstring)."""
+    backend = jax.default_backend()
     env = os.environ.get("REPRO_INTERPRET")
-    if env is not None:
-        return env.strip().lower() not in ("0", "false", "no", "")
-    return jax.default_backend() == "cpu"
+    forced = (None if env is None
+              else env.strip().lower() not in ("0", "false", "no", ""))
+    if backend != "cpu":
+        if forced:
+            raise RuntimeError(
+                f"REPRO_INTERPRET={env!r} asks for interpreted Pallas "
+                f"kernels on the {backend} backend; kernels compile there. "
+                f"Unset REPRO_INTERPRET.")
+        return False
+    return True if forced is None else forced
 
 
 def _resolve_interpret(interpret: Optional[bool]) -> bool:
@@ -57,7 +63,8 @@ def _pad_axis(x, mult, axis, value=0):
 
 def mh_sample(rng: "MHRandoms", z0, nwk_rows, ndk_rows, nk,
               aprob_rows, aalias_rows, cfg: "LDAConfig", *,
-              tile_tokens: int = 1024, interpret: Optional[bool] = None,
+              tile_tokens: Optional[int] = None,
+              interpret: Optional[bool] = None,
               frozen: bool = False) -> jax.Array:
     """Fused MH chain for one block of tokens (kernels/mh_sample.py).
 
@@ -71,7 +78,8 @@ def mh_sample(rng: "MHRandoms", z0, nwk_rows, ndk_rows, nk,
     """
     interpret = _resolve_interpret(interpret)
     b = z0.shape[0]
-    bp = b + ((-b) % tile_tokens)
+    if tile_tokens is None:
+        tile_tokens = _mh.token_tile(cfg.K + (-cfg.K) % LANES)
 
     def prep_rows(x, fill=0.0):
         x = _pad_axis(x.astype(jnp.float32) if x.dtype != jnp.int32 else x,
@@ -141,7 +149,7 @@ def delta_apply_coo(rows, cols, vals, num_rows: int, num_topics: int, *,
     return out[:num_rows, :num_topics]
 
 
-def alias_build(weights, *, tile_rows: int = 64,
+def alias_build(weights, *, tile_rows: int = 128,
                 interpret: Optional[bool] = None) -> "alias_mod.AliasTable":
     """Alias-table construction via the Pallas kernel
     (kernels/alias_build.py).
@@ -152,8 +160,9 @@ def alias_build(weights, *, tile_rows: int = 64,
     from both stacks -> provably never emitted as alias targets) and rows
     to the tile.  The kernel runs the sequential 2K retirement loop.
 
-    Matches ``alias.build_alias_rows`` on the induced pmf (asserted in
-    tests; alias assignments themselves are permutation-dependent).
+    Bitwise ``alias.build_alias_rows``: the same stacks in the same order
+    and the same retirement steps, so a kernel sweep stays bit-identical
+    to the oracle sweep.
     """
     interpret = _resolve_interpret(interpret)
     v, k = weights.shape
@@ -163,7 +172,9 @@ def alias_build(weights, *, tile_rows: int = 64,
     kp = q.shape[1]
     idx = jnp.arange(kp, dtype=jnp.int32)[None, :]
     is_small = q < 1.0
-    is_large = q > 1.0
+    # every real column that is not small is large (Vose's split, as in
+    # the oracle); only the padded columns stay out of both stacks
+    is_large = ~is_small & (idx < k)
     # smalls (then larges) packed to the front, ascending
     small = jnp.argsort(jnp.where(is_small, idx, idx + kp),
                         axis=1).astype(jnp.int32)
@@ -176,8 +187,8 @@ def alias_build(weights, *, tile_rows: int = 64,
     q = _pad_axis(q, tile_rows, axis=0, value=1.0)
     small = _pad_axis(small, tile_rows, axis=0)
     large = _pad_axis(large, tile_rows, axis=0)
-    ns = _pad_axis(ns[None, :], tile_rows, axis=1)
-    nl = _pad_axis(nl[None, :], tile_rows, axis=1)
+    ns = _pad_axis(ns[:, None], tile_rows, axis=0)
+    nl = _pad_axis(nl[:, None], tile_rows, axis=0)
 
     from repro.kernels import alias_build as _ab
     prob, alias_idx = _ab.alias_build_call(

@@ -7,10 +7,12 @@ the job and runs it through ``api.Session``.
 Single-process:
   PYTHONPATH=src python -m repro.launch.lda --docs 2000 --vocab 5000 -k 100
 
-Distributed (SPMD over N host devices; on a pod this is the production
-mesh): workers = all mesh shards (tokens split over data x model), servers =
-the model axis (cyclic rows of n_wk, paper section 2.2):
-  PYTHONPATH=src python -m repro.launch.lda --devices 8 --mesh-model 2 ...
+Distributed (SPMD over N devices): workers = all mesh shards (tokens
+split over data x model), servers = the model axis (cyclic rows of n_wk,
+paper section 2.2).  On a TPU host ``--devices N`` needs N chips; under
+``JAX_PLATFORMS=cpu`` it forces N host devices (appended to XLA_FLAGS):
+  JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.lda --devices 8 \
+      --mesh-model 2 ...
 
 Out-of-core: ``--stream-dir`` streams a sharded on-disk corpus through
 the PS client (optionally combined with ``--devices``: groups of stream
@@ -29,17 +31,23 @@ import sys
 
 
 def _early_devices():
-    if "--devices" in sys.argv:
+    """``--devices N`` on the CPU backend: force N host devices before jax
+    initialises (a TPU host has its chips already)."""
+    if "--devices" in sys.argv and os.environ.get("JAX_PLATFORMS") == "cpu":
         n = sys.argv[sys.argv.index("--devices") + 1]
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={n}")
+        os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+            os.environ.get("XLA_FLAGS"),
+            f"--xla_force_host_platform_device_count={n}")))
 
 
 _early_devices()
 
 import json
 
+import jax
+
 from repro import api
+from repro.compile_cache import enable_compile_cache
 # SPMD wiring lives in the api session now; re-exported here because the
 # SPMD test/benchmark suites import it from the launcher.
 from repro.api.session import (init_distributed_state,  # noqa: F401
@@ -164,7 +172,9 @@ def main():
     ap.add_argument("--kernels", action="store_true",
                     help="use the Pallas kernel path (interpret on CPU)")
     ap.add_argument("--devices", type=int, default=0,
-                    help="force N host devices and run distributed")
+                    help="run distributed over N devices: N chips on a TPU "
+                         "host, N forced host devices under "
+                         "JAX_PLATFORMS=cpu")
     ap.add_argument("--mesh-model", type=int, default=2)
     ap.add_argument("--backend", default="",
                     choices=["", api.IN_PROCESS, api.SPMD, api.NET],
@@ -217,6 +227,12 @@ def main():
                     help="resume the stream trainer from --checkpoint "
                          "(bitwise-identical continuation)")
     args = ap.parse_args()
+    enable_compile_cache()
+    if args.devices and jax.device_count() != args.devices:
+        ap.error(f"--devices {args.devices}: this host has "
+                 f"{jax.device_count()} {jax.default_backend()} device(s); "
+                 f"on a TPU host pass its chip count, or run under "
+                 f"JAX_PLATFORMS=cpu to force host devices")
 
     if args.stream_dir:
         print(f"[lda] stream mode: training {args.epochs} epochs "

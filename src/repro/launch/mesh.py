@@ -10,13 +10,14 @@ from typing import Optional, Tuple
 
 import jax
 
+from repro.sharding.mesh import make_mesh
 from repro.sharding.specs import MeshCtx
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def ctx_for(mesh) -> MeshCtx:
@@ -31,4 +32,4 @@ def make_host_mesh(model: int = 1, data: Optional[int] = None):
     """Small mesh over whatever local devices exist (tests/examples)."""
     n = jax.device_count()
     data = data or (n // model)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

@@ -102,7 +102,8 @@ def run_smoke(workers: int = 4, epochs: int = 2, topics: int = 8,
         base = WorkerConfig(server=address, stream_dir=net_dir,
                             num_topics=topics, block_tokens=512, seed=0,
                             commit_hot_rows=32, fault="once_per_op")
-        pool = WorkerPool(address, base, log_fn=log)
+        pool = WorkerPool(address, base, env={"JAX_PLATFORMS": "cpu"},
+                          log_fn=log)
         pool.start(workers)
 
         # wait until training is genuinely mid-flight, then SIGKILL one

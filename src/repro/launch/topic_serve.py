@@ -29,6 +29,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import lightlda as lda
 from repro.data import corpus as corpus_mod
 from repro.infer.engine import DeadlineExceeded, EngineConfig
@@ -216,8 +217,8 @@ def main():
     ap.add_argument("--mh-steps", type=int, default=2)
     ap.add_argument("--block-tokens", type=int, default=8192)
     ap.add_argument("--kernels", action="store_true",
-                    help="Pallas kernel path (interpret resolved by "
-                         "kernels.ops.default_interpret / REPRO_INTERPRET)")
+                    help="Pallas kernel path (interpreted on the CPU, "
+                         "compiled on a TPU)")
     ap.add_argument("--hot-words", type=int, default=None,
                     help="training push route: H hottest words dense, cold "
                          "tail as coordinate deltas (default: all dense)")
@@ -258,6 +259,7 @@ def main():
                     help="sweeps the background trainer runs during the "
                          "concurrent phase")
     args = ap.parse_args()
+    enable_compile_cache()
     if not 0 <= args.foldin_burnin < args.foldin_sweeps:
         ap.error(f"--foldin-burnin ({args.foldin_burnin}) must be in "
                  f"[0, --foldin-sweeps) (sweeps={args.foldin_sweeps})")

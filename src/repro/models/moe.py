@@ -217,8 +217,7 @@ def moe_block_spmd(params: dict, x: jax.Array, cfg: ModelConfig, mesh,
     shared_spec = jax.tree.map(lambda _: P(), shared) if shared is not None else None
     expert_spec = jax.tree.map(
         lambda _: P(model_axis, tuple(dp_axes), None), params["experts"])
-    from repro.sharding.compat import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(token_spec, P(), expert_spec, shared_spec),
         out_specs=(token_spec, P()),

@@ -17,7 +17,7 @@ Two invariants, enforced here rather than at every call site:
   * **no-op under jit** -- a span opened while jax is *tracing* (inside
     ``jit``/``scan``) would record compile-time, not run-time, and a
     ``block_until_ready`` on a Tracer would fail.  ``_host_time_ok``
-    checks ``jax.core.trace_state_clean()`` (lazily -- this module never
+    checks ``jax.core.trace_ctx.is_top_level()`` (lazily -- this module never
     imports jax itself, keeping numpy-only importers like
     ``repro.data.stream`` jax-free) and the span degrades to ``NULL_SPAN``.
 
@@ -46,10 +46,7 @@ def _host_time_ok() -> bool:
     jax = sys.modules.get("jax")
     if jax is None:
         return True
-    try:
-        return jax.core.trace_state_clean()
-    except Exception:
-        return True
+    return jax.core.trace_ctx.is_top_level()
 
 
 def _block(value: Any) -> None:

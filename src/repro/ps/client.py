@@ -400,8 +400,8 @@ class PSClient:
     ``backend`` supplies the collectives (``InProcessBackend`` /
     ``SpmdBackend``); ``interpret`` is the client-level Pallas-interpret
     default threaded to every kernel call issued through handles (None:
-    resolved by ``kernels.ops.default_interpret`` -- the ``REPRO_INTERPRET``
-    env var, else interpret-on-CPU / compiled-on-TPU).
+    resolved by ``kernels.ops.default_interpret`` -- interpret on the CPU,
+    compiled on a TPU).
     """
 
     backend: Backend = InProcessBackend()

@@ -22,10 +22,10 @@ from typing import Dict, List, Optional
 from repro.ps.net.transport import NetClient
 from repro.ps.net.worker import WorkerConfig
 
-# one BLAS/XLA thread per worker: the pool multiplexes cores across
-# processes, not within one
-_ENV_CAPS = {"JAX_PLATFORMS": "cpu", "OMP_NUM_THREADS": "1",
-             "OPENBLAS_NUM_THREADS": "1", "MKL_NUM_THREADS": "1",
+# CPU workers get one BLAS/XLA thread each: the pool multiplexes cores
+# across processes, not within one
+_CPU_CAPS = {"OMP_NUM_THREADS": "1", "OPENBLAS_NUM_THREADS": "1",
+             "MKL_NUM_THREADS": "1",
              "XLA_FLAGS": "--xla_cpu_multi_thread_eigen=false "
                           "intra_op_parallelism_threads=1"}
 
@@ -41,13 +41,25 @@ class _Proc:
 
 
 class WorkerPool:
-    """Supervise N worker subprocesses against one server address."""
+    """Supervise N worker subprocesses against one server address.
+
+    Workers run on the JAX platform their environment names
+    (``JAX_PLATFORMS``, inherited from this process unless ``env``
+    overrides it).  ``"cpu"`` -- asked for by name -- gives CPU workers,
+    and the pool says so.  Any other setting leaves each worker on the
+    host's accelerator, and a chip belongs to one process: such a pool
+    holds at most one worker, spawned by a parent that does not hold the
+    chip itself.
+    """
 
     def __init__(self, server: str, base_cfg: WorkerConfig, *,
                  env: Optional[Dict[str, str]] = None, log_fn=None):
         self.server = server
         self.base_cfg = base_cfg
-        self.env = dict(os.environ, **_ENV_CAPS, **(env or {}))
+        self.env = dict(os.environ, **(env or {}))
+        self.cpu_workers = self.env.get("JAX_PLATFORMS") == "cpu"
+        if self.cpu_workers:
+            self.env.update(_CPU_CAPS)
         self.log_fn = log_fn or (lambda *a: None)
         self.procs: List[_Proc] = []
         self._ctl: Optional[NetClient] = None
@@ -63,6 +75,12 @@ class WorkerPool:
     def add_worker(self, **overrides) -> int:
         """Spawn one worker subprocess; returns its pool index."""
         i = len(self.procs)
+        if not self.cpu_workers and i >= 1:
+            raise ValueError(
+                "worker processes without JAX_PLATFORMS=cpu each take the "
+                "host's accelerator, and a chip belongs to one process: a "
+                "second worker cannot share it.  Ask for CPU workers by "
+                "name (JAX_PLATFORMS=cpu) or run one worker.")
         cfg = WorkerConfig(**{**self.base_cfg.__dict__, **overrides,
                               "name": overrides.get("name", f"w{i}")})
         proc = subprocess.Popen(
@@ -70,7 +88,9 @@ class WorkerPool:
             env=self.env, cwd=os.getcwd(),
             stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
         self.procs.append(_Proc(proc, cfg))
-        self.log_fn(f"[pool] spawned worker {i} (pid {proc.pid})")
+        where = "CPU worker (JAX_PLATFORMS=cpu)" if self.cpu_workers else \
+            "worker on the default JAX platform"
+        self.log_fn(f"[pool] spawned {where} {i} (pid {proc.pid})")
         return i
 
     def start(self, n: int, **overrides) -> "WorkerPool":

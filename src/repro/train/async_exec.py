@@ -59,7 +59,6 @@ import numpy as np
 
 from repro import obs as _obs
 from repro import ps
-from repro.core import alias as alias_mod
 from repro.core import lightlda as lda
 from repro.obs import ObsConfig
 from repro.obs.trace import _block
@@ -225,7 +224,8 @@ def pipelined_sweep(state: "lda.SamplerState", key: jax.Array,
         # 2. alias tables for the group's rows only
         weights = (rows.astype(jnp.float32) + cfg.beta) / (
             nk.astype(jnp.float32)[None, :] + cfg.V * cfg.beta)
-        table = alias_mod.build_alias_rows(weights)
+        table = lda.build_alias_tables(weights, cfg.use_kernels,
+                                  cfg.kernel_interpret)
 
         # 3. fused resample of the group's tokens against the stale view
         idx = gidx[grp]
@@ -306,7 +306,6 @@ def snapshot_sweep(state: "lda.SamplerState", key: jax.Array,
     ``axis_name``/``model_axis`` kwargs override the handle's backend.
     ``staleness=0`` reproduces the per-block schedule exactly.
     """
-    num_docs = state.ndk.shape[0]
     n = state.w.shape[0]
     nblocks = n // cfg.block_tokens
     s = effective_staleness(nblocks, staleness)
@@ -329,12 +328,13 @@ def snapshot_sweep(state: "lda.SamplerState", key: jax.Array,
     nk_snap = state.nk.value                            # [K]
 
     # --- alias tables from the snapshot (paper section 3, ref [14]) ---
-    # NOTE: always the jnp construction here so the kernel sweep is
-    # bit-identical to the oracle sweep (see lightlda.sweep's original
-    # note; the Pallas alias_build kernel is exercised via its own tests).
+    # The kernel path builds them with the Pallas kernel, bitwise the
+    # jnp construction: the kernel sweep stays bit-identical to the
+    # oracle sweep, and at real V x K the jnp build dominates a sweep.
     weights = (snapshot.astype(jnp.float32) + cfg.beta) / (
         nk_snap.astype(jnp.float32)[None, :] + cfg.V * cfg.beta)
-    table = alias_mod.build_alias_rows(weights)
+    table = lda.build_alias_tables(weights, cfg.use_kernels,
+                                  cfg.kernel_interpret)
 
     w_groups = state.w.reshape(n_groups, gtok)
     d_groups = state.d.reshape(n_groups, gtok)
@@ -376,8 +376,9 @@ def snapshot_sweep(state: "lda.SamplerState", key: jax.Array,
                         changed=changed),
             cfg.V, cfg.K, use_kernels=cfg.use_kernels, prefix_rows=True,
             interpret=cfg.kernel_interpret)
-        d_nk, d_ndk = token_deltas(d_b, z0, z_new, changed, num_docs,
-                                   cfg.K)
+        amt = changed.astype(jnp.int32)
+        d_nk = (jnp.zeros((cfg.K,), jnp.int32)
+                .at[z0].add(-amt).at[z_new].add(amt))
         # SPMD "push": merge each half of the plan over the workers once
         # per group (identity in-process).  The dense part -- the
         # hybrid's [H, K] hot prefix, never padded to [V, K] -- sums
@@ -404,11 +405,13 @@ def snapshot_sweep(state: "lda.SamplerState", key: jax.Array,
                 safe = jnp.clip(c_rows, 0, cfg.V - 1)
                 nwk_dense = nwk_dense.at[safe, c_cols].add(c_vals)
         d_nk = backend.reduce(d_nk)
-        # n_dk stays local: docs are owned by one worker (paper sec. 3).
+        # n_dk stays local: docs are owned by one worker (paper sec. 3),
+        # and merges in place -- never through a [D, K] delta per group.
+        ndk = ndk.at[d_b, z0].add(-amt).at[d_b, z_new].add(amt)
 
         z_flat = jax.lax.dynamic_update_slice_in_dim(
             z_flat, z_new, grp * gtok, axis=0)
-        return (z_flat, ndk + d_ndk, nwk_dense, nk + d_nk), ()
+        return (z_flat, ndk, nwk_dense, nk + d_nk), ()
 
     keys = jax.random.split(key, n_groups)
     carry = (state.z, state.ndk, snapshot, nk_snap)
@@ -679,7 +682,8 @@ def make_tiered_executor(state: "lda.SamplerState", cfg: "lda.LDAConfig",
         cap = idx.shape[0]
         weights = (rows.astype(jnp.float32) + cfg.beta) / (
             nk.astype(jnp.float32)[None, :] + cfg.V * cfg.beta)
-        table = alias_mod.build_alias_rows(weights)
+        table = lda.build_alias_tables(weights, cfg.use_kernels,
+                                  cfg.kernel_interpret)
         wb = jnp.take(w_dev, idx)
         db = jnp.take(d_dev, idx)
         z0 = jnp.take(z_flat, idx)

@@ -30,6 +30,7 @@ from repro import ps
 from repro.core import lightlda as lda
 from repro.data import corpus as corpus_mod
 from repro.data import stream as stream_mod
+from repro.sharding.mesh import make_mesh
 from repro.train import async_exec
 
 
@@ -389,7 +390,7 @@ class TestSpmdPlanes:
                             block_tokens=256, num_shards=mesh_model)
         n_dev = jax.device_count()
         data = n_dev // mesh_model
-        mesh = jax.make_mesh((data, mesh_model), ("data", "model"))
+        mesh = make_mesh((data, mesh_model), ("data", "model"))
         workers = data * mesh_model
         key = jax.random.PRNGKey(seed)
         (w, d, valid, ds, dl, z, ndk, nwk,

@@ -22,6 +22,7 @@ import pytest
 from conftest import make_lda_state
 from repro.core import lightlda as lda
 from repro.data import corpus as corpus_mod
+from repro.sharding.mesh import make_mesh
 from repro.train import async_exec
 from repro.train import loop as train_loop
 
@@ -228,7 +229,7 @@ class TestDistributedExecutor:
 
         model = 2
         data = jax.device_count() // model
-        mesh = jax.make_mesh((data, model), ("data", "model"))
+        mesh = make_mesh((data, model), ("data", "model"))
         workers = data * model
         corp = corpus_mod.generate_lda_corpus(
             seed=0, num_docs=80, mean_doc_len=30, vocab_size=200,

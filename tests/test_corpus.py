@@ -82,6 +82,56 @@ class TestSharding:
         assert train.num_tokens + held.num_tokens == corp.num_tokens
 
 
+def _loop_lda_corpus(seed, num_docs, mean_doc_len, vocab_size, num_topics,
+                     doc_block, zipf_exponent=1.05, doc_topic_alpha=0.08,
+                     topic_concentration=2000.0):
+    """Token-by-token reference for ``generate_lda_corpus``: the same
+    draws from the same stream, each inverted against its own row's CDF."""
+    rng = np.random.default_rng(seed)
+    base = 1.0 / np.arange(1, vocab_size + 1) ** zipf_exponent
+    base /= base.sum()
+    phi = rng.dirichlet(base * topic_concentration, size=num_topics)
+    doc_lens = np.maximum(rng.poisson(mean_doc_len, size=num_docs), 4)
+    d = np.repeat(np.arange(num_docs), doc_lens)
+    z = []
+    for b0 in range(0, num_docs, doc_block):
+        b1 = min(b0 + doc_block, num_docs)
+        theta = rng.dirichlet(np.full(num_topics, doc_topic_alpha),
+                              size=b1 - b0)
+        u = rng.random(int(doc_lens[b0:b1].sum()))
+        for doc, ui in zip(d[d >= b0][:len(u)], u):
+            cdf = np.cumsum(theta[doc - b0])
+            cdf[-1] = 1.0
+            z.append(min(int(np.searchsorted(cdf, ui, side="right")),
+                         num_topics - 1))
+    z = np.asarray(z)
+    w = np.empty(len(z), np.int64)
+    for k in range(num_topics):
+        tok = np.nonzero(z == k)[0]
+        u = rng.random(tok.size)
+        cdf = np.cumsum(phi[k])
+        cdf[-1] = 1.0
+        for t, ui in zip(tok, u):
+            w[t] = min(int(np.searchsorted(cdf, ui, side="right")),
+                       vocab_size - 1)
+    return corpus_mod.reindex(w, d, vocab_size)
+
+
+@pytest.mark.parametrize("doc_block", [7, 64])
+def test_generator_matches_token_loop(doc_block):
+    """The vectorised generator draws exactly what the token-by-token
+    generative process draws from the same stream (one doc block and
+    several)."""
+    args = dict(seed=3, num_docs=40, mean_doc_len=12, vocab_size=150,
+                num_topics=6)
+    got = corpus_mod.generate_lda_corpus(doc_block=doc_block, **args)
+    want = _loop_lda_corpus(doc_block=doc_block, **args)
+    np.testing.assert_array_equal(got.w, want.w)
+    np.testing.assert_array_equal(got.d, want.d)
+    np.testing.assert_array_equal(got.word_freq, want.word_freq)
+    _assert_corpus_consistent(got)
+
+
 class TestShardEdgeCases:
     """The cases that exposed the padding/offsets bug: shards with no
     documents, and blocks bigger than a shard's token count."""

@@ -81,6 +81,7 @@ def test_moe_spmd_matches_dense():
         from jax.sharding import PartitionSpec as P
         from repro.configs.base import ModelConfig
         from repro.models import moe
+        from repro.sharding.mesh import make_mesh
         from repro.sharding.specs import MeshCtx
 
         cfg = ModelConfig(name="t", arch_type="moe", num_layers=1,
@@ -93,7 +94,7 @@ def test_moe_spmd_matches_dense():
 
         y_ref, aux_ref = moe.moe_block(params, x, cfg, None)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         ctx = MeshCtx(mesh, ("data",), "model")
         # storage-shard the experts like specs.py would
         y_spmd, aux_spmd = jax.jit(
@@ -116,11 +117,12 @@ def test_lm_train_step_on_mesh():
         import jax, jax.numpy as jnp
         from repro.configs import registry
         from repro.configs.base import TrainConfig
+        from repro.sharding.mesh import make_mesh
         from repro.sharding.specs import MeshCtx
         from repro.train import loop as train_loop
 
         cfg = registry.smoke_variant("gemma3-4b")
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         ctx = MeshCtx(mesh, ("data",), "model")
         state = train_loop.init_state(jax.random.PRNGKey(0), cfg, ctx)
         tc = TrainConfig(total_steps=5, warmup_steps=1, microbatch=2)
@@ -141,9 +143,9 @@ def test_pserver_spmd_pull_push():
         from jax.sharding import PartitionSpec as P
         from repro import ps
         from repro.core.pserver import spmd_pull_all, spmd_push_reduce
-        from repro.sharding.compat import shard_map
+        from repro.sharding.mesh import make_mesh
 
-        mesh = jax.make_mesh((8,), ("model",))
+        mesh = make_mesh((8,), ("model",))
         dense = jnp.arange(64, dtype=jnp.int32).reshape(16, 4)
         client = ps.PSClient.create(num_shards=8)
         m = client.matrix_from_dense(dense)
@@ -154,7 +156,7 @@ def test_pserver_spmd_pull_push():
             mine = spmd_push_reduce(delta, "model", None, 8)
             return full, local + mine
 
-        f = shard_map(body, mesh=mesh, in_specs=P("model", None),
+        f = jax.shard_map(body, mesh=mesh, in_specs=P("model", None),
                       out_specs=(P(None, None), P("model", None)),
                       check_vma=False)
         full, updated = jax.jit(f)(m.value)

@@ -196,6 +196,22 @@ class TestAliasBuildKernel:
         # alias targets must never point at padded columns
         assert int(np.asarray(got.alias).max()) < k
 
+    @pytest.mark.parametrize("v,k", [(64, 33), (37, 130)])
+    def test_bitwise_matches_oracle(self, v, k):
+        """Same prob and alias arrays as the jnp Vose build, including rows
+        with weights exactly at the mean (q == 1 joins the large stack in
+        both), so a kernel sweep can use it and stay bit-identical."""
+        key = jax.random.PRNGKey(v + k)
+        cnt = jax.random.poisson(key, 0.5, (v, k)).astype(jnp.float32)
+        at_mean = jnp.ones((3, k)).at[:, 0].set(2.0).at[:, 1:3].set(0.5)
+        w = jnp.concatenate([cnt + 0.01, at_mean])
+        got = kops.alias_build(w, tile_rows=32)
+        ref = kref.alias_build_ref(w)
+        np.testing.assert_array_equal(np.asarray(got.prob),
+                                      np.asarray(ref.prob))
+        np.testing.assert_array_equal(np.asarray(got.alias),
+                                      np.asarray(ref.alias))
+
     def test_uniform_row(self):
         from repro.core import alias as alias_mod
         w = jnp.ones((4, 10))

@@ -101,3 +101,23 @@ class TestThreeWayComparison:
         # same ballpark (paper: within ~10% of each other across Table 1)
         assert abs(p_light - p_em) / min(p_light, p_em) < 0.15, \
             (p_light, p_em)
+
+
+@pytest.mark.parametrize("chunk", [128, 1000])
+def test_log_likelihood_chunked_matches_whole(corp, chunk):
+    """Scoring tokens a chunk at a time (what keeps a 10^8-token corpus
+    off [N, K] temporaries) sums the same log-likelihood, to f32
+    summation-order tolerance."""
+    k = 8
+    theta = jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(0),
+                                             (corp.num_docs, k)))
+    phi = jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(1),
+                                           (corp.vocab_size, k)), axis=0)
+    w, d = jnp.asarray(corp.w), jnp.asarray(corp.d)
+    valid = jnp.arange(corp.num_tokens) % 7 != 0
+    whole = ppl.log_likelihood(w, d, valid, theta, phi, corp.num_docs,
+                               chunk=corp.num_tokens)
+    chunked = ppl.log_likelihood(w, d, valid, theta, phi, corp.num_docs,
+                                 chunk=chunk)
+    assert corp.num_tokens > chunk
+    np.testing.assert_allclose(float(chunked), float(whole), rtol=1e-5)

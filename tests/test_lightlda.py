@@ -114,3 +114,26 @@ class TestHeldout:
             w, d, jnp.asarray(coin), w, d, jnp.asarray(~coin),
             phi, held.num_docs, cfg.alpha))
         assert p < 400  # uniform model would give exactly V = 400
+
+    def test_packed_heldout_matches_flat(self, small_setup):
+        """The [D, L] document-major evaluation computes the flat
+        gather/scatter-add estimator, to f32 summation-order tolerance."""
+        corp, cfg, state = small_setup
+        state = lda.train(state, jax.random.PRNGKey(7), cfg, 5)
+        phi = ppl.phi_from_counts(state.nwk.to_dense().astype(jnp.float32),
+                                  state.nk.value.astype(jnp.float32),
+                                  cfg.beta)
+        held = corpus_mod.generate_lda_corpus(
+            seed=9, num_docs=40, mean_doc_len=50, vocab_size=400,
+            num_topics=8)
+        w, d, fold, ev = corpus_mod.fold_eval_split(held)
+        w, d = jnp.asarray(w), jnp.asarray(d)
+        flat = float(ppl.heldout_perplexity(
+            w, d, jnp.asarray(fold), w, d, jnp.asarray(ev), phi,
+            held.num_docs, cfg.alpha))
+        packed = corpus_mod.packed_fold_eval_split(held)
+        assert packed[0].shape == (held.num_docs, held.doc_len.max())
+        assert packed[1].sum() == fold.sum() and packed[2].sum() == ev.sum()
+        got = float(ppl.heldout_perplexity_packed(
+            *map(jnp.asarray, packed), phi, cfg.alpha))
+        np.testing.assert_allclose(got, flat, rtol=1e-5)

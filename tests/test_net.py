@@ -492,3 +492,31 @@ class TestNetJobValidation:
         with pytest.raises(api.JobValidationError, match="backend"):
             api.LDAJob(corpus=tiny_corpus, num_topics=K,
                        server="127.0.0.1:1").validate()
+
+
+class TestWorkerPlatform:
+    """Workers run where ``JAX_PLATFORMS`` says; nothing forces the CPU."""
+
+    def _pool(self, platforms, lines):
+        from repro.ps.net import WorkerPool
+        base = WorkerConfig(server="localhost:1", stream_dir="unused",
+                            num_topics=K)
+        return WorkerPool("localhost:1", base,
+                          env={"JAX_PLATFORMS": platforms},
+                          log_fn=lines.append)
+
+    def test_cpu_workers_by_name(self):
+        pool = self._pool("cpu", [])
+        assert pool.cpu_workers
+        assert pool.env["JAX_PLATFORMS"] == "cpu"
+        assert pool.env["OMP_NUM_THREADS"] == "1"
+
+    def test_accelerator_workers_hold_one_chip(self):
+        lines = []
+        pool = self._pool("tpu", lines)
+        assert not pool.cpu_workers
+        assert pool.env["JAX_PLATFORMS"] == "tpu"
+        pool.procs.append(None)          # one worker already holds the chip
+        with pytest.raises(ValueError, match="JAX_PLATFORMS=cpu"):
+            pool.add_worker()
+        assert lines == []

@@ -380,6 +380,18 @@ class TestInterpretDefault:
         if jax.default_backend() == "cpu":
             assert ops.default_interpret() is True
 
+    def test_interpret_env_is_an_error_on_accelerators(self, monkeypatch):
+        """An accelerator never falls back to the Pallas interpreter."""
+        from repro.kernels import ops
+        monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+        monkeypatch.delenv("REPRO_INTERPRET", raising=False)
+        assert ops.default_interpret() is False
+        monkeypatch.setenv("REPRO_INTERPRET", "0")
+        assert ops.default_interpret() is False
+        monkeypatch.setenv("REPRO_INTERPRET", "1")
+        with pytest.raises(RuntimeError, match="REPRO_INTERPRET"):
+            ops.default_interpret()
+
     def test_kernel_calls_resolve_none(self):
         """interpret=None flows end-to-end (would raise inside pallas if
         unresolved)."""
@@ -409,7 +421,7 @@ class TestBackendParity:
 
     @pytest.mark.parametrize("route", ROUTES)
     def test_spmd_matches_in_process(self, route):
-        from repro.sharding.compat import shard_map
+        from repro.sharding.mesh import make_mesh
         from jax.sharding import PartitionSpec as P
 
         self.route = route
@@ -423,7 +435,7 @@ class TestBackendParity:
         want = np.asarray(host.to_dense())
 
         # --- SPMD: each worker pushes its own batch, psum merges ---
-        mesh = jax.make_mesh((n_dev,), ("x",))
+        mesh = make_mesh((n_dev,), ("x",))
         client = ps.PSClient.create(num_shards=2, axis_name="x")
 
         def worker(base_rep, re):
@@ -435,7 +447,7 @@ class TestBackendParity:
 
         stacked = jax.tree.map(
             lambda *xs: jnp.stack(xs), *batches)
-        fn = shard_map(worker, mesh=mesh,
+        fn = jax.shard_map(worker, mesh=mesh,
                        in_specs=(P(), P("x", None)), out_specs=P(),
                        check_vma=False)
         got = np.asarray(fn(base, stacked))
@@ -447,7 +459,7 @@ class TestBackendParity:
         pre-partitioned batch with a (common, understated-safe)
         hot_prefix; the prefix dense psums, the COO buffers all-gather,
         and every replica lands on the in-process result bitwise."""
-        from repro.sharding.compat import shard_map
+        from repro.sharding.mesh import make_mesh
         from jax.sharding import PartitionSpec as P
 
         v, k, hot = 19, 6, 5
@@ -468,7 +480,7 @@ class TestBackendParity:
             h0 = h0.push(re_p, hot_prefix=hp)
         want = np.asarray(h0.to_dense())
 
-        mesh = jax.make_mesh((n_dev,), ("x",))
+        mesh = make_mesh((n_dev,), ("x",))
         client = ps.PSClient.create(num_shards=2, axis_name="x")
 
         def worker(base_rep, re):
@@ -478,7 +490,7 @@ class TestBackendParity:
 
         stacked = jax.tree.map(
             lambda *xs: jnp.stack(xs), *[p[0] for p in parts])
-        fn = shard_map(worker, mesh=mesh,
+        fn = jax.shard_map(worker, mesh=mesh,
                        in_specs=(P(), P("x", None)), out_specs=P(),
                        check_vma=False)
         got = np.asarray(fn(base, stacked))
@@ -487,13 +499,13 @@ class TestBackendParity:
     def test_model_sharded_pull_all(self):
         """pull_all on a model-sharded handle all-gathers the cyclic rows
         back into the full dense matrix on every worker."""
-        from repro.sharding.compat import shard_map
+        from repro.sharding.mesh import make_mesh
         from jax.sharding import PartitionSpec as P
 
         shards = 2
         v, k = 10, 4
         dense = jnp.arange(v * k, dtype=jnp.int32).reshape(v, k)
-        mesh = jax.make_mesh((shards,), ("model",))
+        mesh = make_mesh((shards,), ("model",))
         full = ps.PSClient.create(num_shards=shards).matrix_from_dense(
             dense)
         client = ps.PSClient.create(num_shards=shards, model_axis="model")
@@ -502,7 +514,7 @@ class TestBackendParity:
             h = client.wrap_matrix(phys_local, v)
             return h.pull_all().result()
 
-        fn = shard_map(worker, mesh=mesh, in_specs=(P("model", None),),
+        fn = jax.shard_map(worker, mesh=mesh, in_specs=(P("model", None),),
                        out_specs=P(), check_vma=False)
         got = np.asarray(fn(full.value))
         np.testing.assert_array_equal(got, np.asarray(dense))

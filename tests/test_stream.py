@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from repro.core import lightlda as lda
 from repro.data import stream as stream_mod
+from repro.sharding.mesh import make_mesh
 from repro.train import async_exec
 from repro.train import loop as train_loop
 
@@ -269,7 +270,7 @@ class TestStreamSpmd:
         assert reader.num_shards >= workers
         cfg = lda.LDAConfig(num_topics=8, vocab_size=corp.vocab_size,
                             block_tokens=256, num_shards=model)
-        mesh = jax.make_mesh((data, model), ("data", "model"))
+        mesh = make_mesh((data, model), ("data", "model"))
         sweep_fn = jax.jit(launch_lda.make_spmd_sweep(mesh, cfg,
                                                       staleness=1))
         meta = reader.meta

@@ -9,9 +9,7 @@ Two halves:
    asserts the overhead is **< 1%** (best-of-repeats on both sides).
 
 2. **Traced demo.**  One obs session covering the whole lifecycle --
-   api-session training (exec.sweep / exec.dispatch spans), the eager
-   group-schedule replay (``repro.obs.exec_trace``: pull.inflight
-   overlapping alias.build/sample/merge.store on separate lanes), one
+   api-session training (exec.sweep / exec.dispatch spans), one
    ``MatrixHandle.push`` per route (dense / coo / hybrid ps.push spans),
    and a ``QueryEngine`` flush (serve.request_ms p50/p99).  The resulting
    ``trace.json`` is Perfetto-loadable; the bench prints the
@@ -34,7 +32,7 @@ from repro.data import corpus as corpus_mod
 from repro.infer.engine import EngineConfig, QueryEngine
 from repro.infer.foldin import FoldInConfig
 from repro.launch import obs_report
-from repro.obs import exec_trace, time_loop
+from repro.obs import time_loop
 from repro.train import async_exec
 
 OUT = "experiments/bench/BENCH_obs.json"
@@ -82,7 +80,7 @@ def main(fast: bool = False):
     print(f"obs,overhead_disabled,{raw_ms:.2f},raw_ms,"
           f"{wrapped_ms:.2f},wrapped_ms,{overhead_pct:+.3f},pct")
 
-    # --- 2. traced demo: one session over train + replay + push + serve --
+    # --- 2. traced demo: one session over train + push + serve -----------
     obs_cfg = obs.ObsConfig(enabled=True, out_dir=OBS_DIR)
     with obs.session(obs_cfg):
         # training through the api session; ExecConfig.obs=None inherits
@@ -91,12 +89,6 @@ def main(fast: bool = False):
                          staleness=2, model_blocks=blocks,
                          sweeps=iters, eval_every=0, seed=0)
         model = api.APSLDA(job, log_fn=lambda *a, **kw: None).fit()
-
-        # eager replay of the same blocked schedule: per-phase spans with
-        # pull.inflight on its own lane, visibly overlapping sampling
-        exec_trace.traced_pipelined_sweep(
-            state, jax.random.PRNGKey(7), cfg, model_blocks=blocks,
-            staleness=2)
 
         # one eager push per route: the per-route ps.push cost table
         client = ps.PSClient.create(num_shards=4)
@@ -130,8 +122,7 @@ def main(fast: bool = False):
 
     events = obs_report.load_trace(os.path.join(OBS_DIR, "trace.json"))
     names = {ev["name"] for ev in events if ev.get("ph") == "X"}
-    for needed in ("exec.sweep", "exec.dispatch", "pull.inflight", "sample",
-                   "merge.store", "ps.push", "engine.flush"):
+    for needed in ("exec.sweep", "exec.dispatch", "ps.push", "engine.flush"):
         assert needed in names, f"traced demo missing {needed!r} spans"
     route_labels = {ev["args"]["route"] for ev in events
                     if ev.get("ph") == "X" and ev["name"] == "ps.push"}

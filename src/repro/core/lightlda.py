@@ -356,16 +356,18 @@ def sample_tokens_frozen(model: FrozenModel, rng: MHRandoms, z0: jax.Array,
     ``use_kernels`` (kernels/ops.py ``frozen=True`` wrapper); otherwise the
     jnp oracle chain.
     """
-    nwk_rows = jnp.take(model.nwk, w, axis=0)
-    aprob_rows = jnp.take(model.aprob, w, axis=0)
-    aalias_rows = jnp.take(model.aalias, w, axis=0)
-    if use_kernels:
-        from repro.kernels import ops as kops
-        return kops.mh_sample(rng, z0, nwk_rows, ndk_rows, model.nk,
-                              aprob_rows, aalias_rows, cfg, frozen=True,
-                              interpret=interpret)
-    return mh_chain(rng, z0, nwk_rows, ndk_rows, model.nk,
-                    aprob_rows, aalias_rows, cfg, frozen=True)
+    with jax.named_scope("ps.pull"):
+        nwk_rows = jnp.take(model.nwk, w, axis=0)
+        aprob_rows = jnp.take(model.aprob, w, axis=0)
+        aalias_rows = jnp.take(model.aalias, w, axis=0)
+    with jax.named_scope("mh.chain"):
+        if use_kernels:
+            from repro.kernels import ops as kops
+            return kops.mh_sample(rng, z0, nwk_rows, ndk_rows, model.nk,
+                                  aprob_rows, aalias_rows, cfg, frozen=True,
+                                  interpret=interpret)
+        return mh_chain(rng, z0, nwk_rows, ndk_rows, model.nk,
+                        aprob_rows, aalias_rows, cfg, frozen=True)
 
 
 # Dense delta aggregation (paper section 3.3) lives in ps/routes.py now:

@@ -173,7 +173,7 @@ class QueryEngine:
         tr = _obs.tracer_for(self.ecfg.foldin.obs)
         flush_sp = (tr.span("engine.flush", cat="serve",
                             requests=len(queue), version=snap.version)
-                    if tr is not None else _obs.NULL_SPAN)
+                    if tr is not None else _obs.annotation("engine.flush"))
         out: Dict[int, Result] = {}
         mb = self.ecfg.max_batch
         for bucket in sorted(buckets):
@@ -183,7 +183,8 @@ class QueryEngine:
                 batch_sp = (tr.span("engine.batch", cat="serve",
                                     bucket=bucket, occupancy=len(chunk),
                                     max_batch=mb)
-                            if tr is not None else _obs.NULL_SPAN)
+                            if tr is not None
+                            else _obs.annotation("engine.batch"))
                 # _run_batch ends on np.asarray: the batch is host-synced
                 # by the time the span closes
                 with batch_sp:
@@ -611,7 +612,7 @@ class ConcurrentEngine:
             sp = (tr.span("engine.batch", cat="serve", bucket=bucket,
                           occupancy=len(batch), trigger=trigger,
                           max_batch=engine.ecfg.max_batch)
-                  if tr is not None else _obs.NULL_SPAN)
+                  if tr is not None else _obs.annotation("engine.batch"))
             with sp:
                 theta = engine._run_batch(snap, reqs, bucket)
         except BaseException as exc:   # noqa: BLE001 -- fail the tickets,

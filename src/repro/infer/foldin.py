@@ -136,21 +136,31 @@ def fold_in_batch(model: lda.FrozenModel, w: jax.Array, valid: jax.Array,
 
     def sweep(s, carry):
         z, ndk_acc = carry
-        sweep_keys = jax.vmap(lambda k: jax.random.fold_in(k, s))(doc_keys)
-        u_w, u_wa, z_d, u_da = jax.vmap(
-            lambda k, zr, n: _doc_randoms(k, zr, n, cfg))(sweep_keys, z, nd)
-        # [B, S, L] -> [S, B*L] flat token order
-        rng = lda.MHRandoms(*(r.transpose(1, 0, 2).reshape(cfg.mh_steps, b * l)
-                              for r in (u_w, u_wa, z_d, u_da)))
-        ndk = _ndk_from_z(z, valid, cfg.K)
-        ndk_rows = jnp.broadcast_to(
-            ndk[:, None, :], (b, l, cfg.K)).reshape(b * l, cfg.K)
+        with jax.named_scope("mh.chain"):
+            sweep_keys = jax.vmap(lambda k: jax.random.fold_in(k, s))(
+                doc_keys)
+            u_w, u_wa, z_d, u_da = jax.vmap(
+                lambda k, zr, n: _doc_randoms(k, zr, n, cfg))(
+                    sweep_keys, z, nd)
+            # [B, S, L] -> [S, B*L] flat token order
+            rng = lda.MHRandoms(*(r.transpose(1, 0, 2)
+                                  .reshape(cfg.mh_steps, b * l)
+                                  for r in (u_w, u_wa, z_d, u_da)))
+        with jax.named_scope("ndk.merge"):
+            ndk = _ndk_from_z(z, valid, cfg.K)
+        with jax.named_scope("ps.pull"):
+            ndk_rows = jnp.broadcast_to(
+                ndk[:, None, :], (b, l, cfg.K)).reshape(b * l, cfg.K)
+        # the model's row gathers (ps.pull) and the chain (mh.chain) are
+        # scoped inside sample_tokens_frozen
         z_new = lda.sample_tokens_frozen(
             model, rng, z.reshape(b * l), w_flat, ndk_rows, cfg,
             use_kernels=fcfg.use_kernels, interpret=fcfg.kernel_interpret)
-        z_new = jnp.where(valid, z_new.reshape(b, l), z)
-        ndk_acc = ndk_acc + jnp.where(
-            s >= fcfg.burnin, _ndk_from_z(z_new, valid, cfg.K), 0)
+        with jax.named_scope("mh.chain"):
+            z_new = jnp.where(valid, z_new.reshape(b, l), z)
+        with jax.named_scope("ndk.merge"):
+            ndk_acc = ndk_acc + jnp.where(
+                s >= fcfg.burnin, _ndk_from_z(z_new, valid, cfg.K), 0)
         return z_new, ndk_acc
 
     _, ndk_acc = jax.lax.fori_loop(

@@ -103,4 +103,5 @@ def alias_build_call(q, small, large, ns, nl, *, num_cols: int,
         out_shape=(jax.ShapeDtypeStruct((v, kp), jnp.float32),
                    jax.ShapeDtypeStruct((v, kp), jnp.int32)),
         interpret=interpret,
+        name="alias_build",
     )(q, small, large, ns, nl)

@@ -91,6 +91,7 @@ def delta_push_call(w, z_old, z_new, changed, *, vocab_pad: int, k_pad: int,
         out_specs=out,
         out_shape=jax.ShapeDtypeStruct((vocab_pad, k_pad), jnp.int32),
         interpret=interpret,
+        name="delta_push",
     )(w, z_old, z_new, changed)
 
 
@@ -183,4 +184,5 @@ def delta_apply_coo_call(rows, cols, vals, *, vocab_pad: int, k_pad: int,
         out_specs=out,
         out_shape=jax.ShapeDtypeStruct((vocab_pad, k_pad), jnp.int32),
         interpret=interpret,
+        name="delta_apply_coo",
     )(rows, cols, vals)

@@ -148,5 +148,6 @@ def mh_sample_call(z0, nwk_rows, ndk_rows, nk, aprob, aalias,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit(tb, kp)),
         interpret=interpret,
+        name="mh_sample_frozen" if frozen else "mh_sample",
     )(z0, nwk_rows, ndk_rows, nk, aprob, aalias,
       u_word, u_waccept, z_doc, u_daccept)

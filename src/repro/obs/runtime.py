@@ -11,8 +11,10 @@ themselves -- they ask this module:
 ``span`` / ``metrics`` return no-op objects unless an ``ObsSession`` is
 installed (``obs.session(cfg)`` context manager, or ``ObsSession(cfg)
 .install()``), so the disabled-mode cost at every call site is one module
-attribute read and one ``is None`` check -- that is what lets
-``bench_obs.py`` hold the <1% overhead bar without any call-site gating.
+attribute read, one ``is None`` check and one test of whether a jax
+profiler records (a span annotates a recording profiler with or without a
+session, ``trace.annotation``) -- that is what lets ``bench_obs.py`` hold
+the <1% overhead bar without any call-site gating.
 
 ``ObsConfig`` is a **frozen, hashable** dataclass of primitives because it
 rides on ``FoldInConfig``/``ExecConfig``, which are jit static argnames:
@@ -36,7 +38,7 @@ import threading
 from typing import Any, Iterator, Optional
 
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import NULL_SPAN, Tracer
+from repro.obs.trace import Tracer, annotation
 
 
 @dataclasses.dataclass(frozen=True)
@@ -121,13 +123,13 @@ def metrics_registry() -> Optional[MetricsRegistry]:
     return s.metrics if s is not None else None
 
 
-def span(name: str, cat: str = "host", sync: Any = None,
-         tid: Optional[int] = None, **args):
-    """Open a span on the installed tracer, or ``NULL_SPAN`` when none."""
+def span(name: str, cat: str = "host", sync: Any = None, **args):
+    """Open a span on the installed tracer; with none, a profiler
+    annotation alone (``NULL_SPAN`` when no profiler records)."""
     t = tracer()
     if t is None:
-        return NULL_SPAN
-    return t.span(name, cat=cat, sync=sync, tid=tid, **args)
+        return annotation(name)
+    return t.span(name, cat=cat, sync=sync, **args)
 
 
 def tracer_for(cfg: Optional[ObsConfig]) -> Optional[Tracer]:

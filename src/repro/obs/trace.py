@@ -1,11 +1,13 @@
 """Host-side span tracing with Chrome-trace/Perfetto JSON output.
 
 A ``Span`` is a host-timed interval (``time.perf_counter_ns``) recorded as
-a Chrome ``"ph": "X"`` complete event.  The tracer is process-wide and
-thread-safe: each thread's spans land on its own track (``tid``), plus
-synthetic *lanes* (tids >= ``LANE_BASE``) for things that are not threads
--- the device stream, the in-flight pull window -- so overlap between host
-dispatch and device/PS work is visible in the Perfetto timeline.
+a Chrome ``"ph": "X"`` complete event.  While a jax profiler is recording,
+a span also opens a ``jax.profiler.TraceAnnotation`` of the same name on
+the thread that opened it -- with or without a tracer (``annotation``) --
+so a profiler trace carries the program's spans on the device trace's
+clock.  That path never syncs; intervals recorded after the fact
+(``Tracer.complete``) stay Chrome-only.  The tracer is process-wide and
+thread-safe: each thread's spans land on its own track (``tid``).
 
 Two invariants, enforced here rather than at every call site:
 
@@ -19,7 +21,8 @@ Two invariants, enforced here rather than at every call site:
     ``block_until_ready`` on a Tracer would fail.  ``_host_time_ok``
     checks ``jax.core.trace_ctx.is_top_level()`` (lazily -- this module never
     imports jax itself, keeping numpy-only importers like
-    ``repro.data.stream`` jax-free) and the span degrades to ``NULL_SPAN``.
+    ``repro.data.stream`` jax-free) and the span degrades to ``NULL_SPAN``
+    (and opens no profiler annotation).
 
 This module is dependency-free (stdlib only) by design.
 """
@@ -32,13 +35,6 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
-# Synthetic track ids for non-thread lanes ("device", "pull", ...).  Real
-# thread ids (``threading.get_ident``) are large opaque ints; we remap them
-# to small stable ones per-process and keep lanes in their own range so the
-# two can never collide.
-LANE_BASE = 1_000_000
-
-
 def _host_time_ok() -> bool:
     """True when it is safe to record host wall time (i.e. we are NOT
     inside a jax trace).  jax is looked up lazily via ``sys.modules`` so
@@ -47,6 +43,27 @@ def _host_time_ok() -> bool:
     if jax is None:
         return True
     return jax.core.trace_ctx.is_top_level()
+
+
+_annotation_cls: Any = None     # jax.profiler.TraceAnnotation, once seen
+
+
+def _open_annotation(name: str) -> Any:
+    """An entered ``jax.profiler.TraceAnnotation`` of ``name`` when a jax
+    profiler is recording and no jax trace is in progress, else None.
+    With no profiler this costs one flag test."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return None
+        cls = _annotation_cls = jax.profiler.TraceAnnotation
+    if not cls.is_enabled() or not _host_time_ok():
+        return None
+    ann = cls(name)
+    ann.__enter__()
+    return ann
 
 
 def _block(value: Any) -> None:
@@ -70,17 +87,16 @@ class Span:
     sync-boundary policy of DESIGN.md section 11.
     """
 
-    __slots__ = ("tracer", "name", "cat", "args", "tid", "_t0", "_sync")
+    __slots__ = ("tracer", "name", "cat", "args", "_t0", "_sync", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
-                 args: Optional[Dict[str, Any]], tid: Optional[int],
-                 sync: Any = None):
+                 args: Optional[Dict[str, Any]], sync: Any = None):
         self.tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
-        self.tid = tid
         self._sync = sync
+        self._ann = _open_annotation(name)
         self._t0 = time.perf_counter_ns()
 
     def sync_on(self, value: Any) -> Any:
@@ -96,13 +112,17 @@ class Span:
         self.args.update(kw)
 
     def end(self) -> float:
-        """Close the span; returns duration in milliseconds."""
+        """Close the span; returns duration in milliseconds.  The
+        profiler annotation closes first: it never covers the sync."""
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         if self._sync is not None and self.tracer.sync_spans:
             _block(self._sync)
             self._sync = None
         t1 = time.perf_counter_ns()
         self.tracer._complete(self.name, self.cat, self._t0, t1,
-                              self.args, self.tid)
+                              self.args)
         return (t1 - self._t0) / 1e6
 
     def __enter__(self) -> "Span":
@@ -137,6 +157,32 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+class _Annotation(_NullSpan):
+    """A span on the profiler alone: what ``annotation`` opens when no
+    tracer records the span.  It never syncs."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, ann: Any):
+        self._ann = ann
+
+    def end(self) -> float:
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        return 0.0
+
+    def __exit__(self, *exc) -> None:
+        self.end()
+
+
+def annotation(name: str):
+    """The span of ``name`` on the profiler alone: an open annotation
+    while a jax profiler records at top level, else ``NULL_SPAN``."""
+    ann = _open_annotation(name)
+    return NULL_SPAN if ann is None else _Annotation(ann)
+
+
 class Tracer:
     """Process-wide Chrome-trace event collector.
 
@@ -151,8 +197,8 @@ class Tracer:
         self.pid = pid if pid else os.getpid()
         self._events: List[dict] = []
         self._lock = threading.Lock()
-        self._tids: Dict[int, int] = {}      # thread ident -> small tid
-        self._lanes: Dict[str, int] = {}     # lane name -> synthetic tid
+        # thread ident (a large opaque int) -> small stable tid
+        self._tids: Dict[int, int] = {}
         self._epoch_ns = time.perf_counter_ns()
 
     # -- track bookkeeping ------------------------------------------------
@@ -169,27 +215,14 @@ class Tracer:
                     "tid": tid, "args": {"name": name}})
         return tid
 
-    def lane(self, name: str) -> int:
-        """A synthetic track for non-thread timelines (device stream,
-        in-flight pulls).  Stable per name."""
-        with self._lock:
-            tid = self._lanes.get(name)
-            if tid is None:
-                tid = LANE_BASE + len(self._lanes)
-                self._lanes[name] = tid
-                self._events.append({
-                    "name": "thread_name", "ph": "M", "pid": self.pid,
-                    "tid": tid, "args": {"name": f"[{name}]"}})
-        return tid
-
     def _us(self, t_ns: int) -> float:
         return (t_ns - self._epoch_ns) / 1e3
 
     # -- event emission ---------------------------------------------------
     def _complete(self, name: str, cat: str, t0_ns: int, t1_ns: int,
-                  args: Optional[dict], tid: Optional[int]) -> None:
+                  args: Optional[dict]) -> None:
         ev = {"name": name, "cat": cat, "ph": "X", "pid": self.pid,
-              "tid": self._tid() if tid is None else tid,
+              "tid": self._tid(),
               "ts": self._us(t0_ns), "dur": (t1_ns - t0_ns) / 1e3}
         if args:
             ev["args"] = args
@@ -197,18 +230,18 @@ class Tracer:
             self._events.append(ev)
 
     def span(self, name: str, cat: str = "host", sync: Any = None,
-             tid: Optional[int] = None, **args) -> Span:
+             **args) -> Span:
         """Open a span.  Under a jax trace this returns ``NULL_SPAN``."""
         if not _host_time_ok():
             return NULL_SPAN
-        return Span(self, name, cat, args or None, tid,
+        return Span(self, name, cat, args or None,
                     sync=sync if self.sync_spans else None)
 
     def complete(self, name: str, t0_ns: int, t1_ns: int, cat: str = "host",
-                 tid: Optional[int] = None, **args) -> None:
-        """Record an already-measured interval (e.g. a lane event whose
-        endpoints were captured elsewhere)."""
-        self._complete(name, cat, t0_ns, t1_ns, args or None, tid)
+                 **args) -> None:
+        """Record an already-measured interval (endpoints captured
+        elsewhere, e.g. around a sync); Chrome-only."""
+        self._complete(name, cat, t0_ns, t1_ns, args or None)
 
     def instant(self, name: str, cat: str = "host", **args) -> None:
         if not _host_time_ok():

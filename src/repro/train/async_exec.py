@@ -61,6 +61,7 @@ from repro import obs as _obs
 from repro import ps
 from repro.core import lightlda as lda
 from repro.obs import ObsConfig
+from repro.obs import scopes as _scopes
 from repro.obs.trace import _block
 
 
@@ -206,8 +207,10 @@ def pipelined_sweep(state: "lda.SamplerState", key: jax.Array,
     # host-side ``make_executor`` instead builds the token index directly
     # at group granularity, which amortises per-block padding; this
     # reshape path serves direct callers with a per-block index.)
-    gidx = block_idx.reshape(n_groups, group * cap)
-    gval = block_valid.reshape(n_groups, group * cap)
+    with jax.named_scope("ps.pull"):
+        gidx = block_idx.reshape(n_groups, group * cap)
+        gval = block_valid.reshape(n_groups, group * cap)
+        groups = jnp.arange(n_groups)
     gcap = group * cap
 
     def group_body(carry, inp):
@@ -218,63 +221,71 @@ def pipelined_sweep(state: "lda.SamplerState", key: jax.Array,
         # next group's pull before sampling.  Exact, not approximate: this
         # group's write-back only touches its own physical rows, so the
         # in-flight pull cannot be invalidated.
-        rows = pulled.result()
-        pulled_next = nwk.pull_block((grp + 1) % n_groups, grp_rows)
+        with jax.named_scope("ps.pull"):
+            rows = pulled.result()
+            pulled_next = nwk.pull_block((grp + 1) % n_groups, grp_rows)
 
         # 2. alias tables for the group's rows only
-        weights = (rows.astype(jnp.float32) + cfg.beta) / (
-            nk.astype(jnp.float32)[None, :] + cfg.V * cfg.beta)
-        table = lda.build_alias_tables(weights, cfg.use_kernels,
-                                  cfg.kernel_interpret)
+        with jax.named_scope("alias.tables"):
+            weights = (rows.astype(jnp.float32) + cfg.beta) / (
+                nk.astype(jnp.float32)[None, :] + cfg.V * cfg.beta)
+            table = lda.build_alias_tables(weights, cfg.use_kernels,
+                                           cfg.kernel_interpret)
 
         # 3. fused resample of the group's tokens against the stale view
-        idx = gidx[grp]
-        vb = gval[grp]
-        wb = jnp.take(state.w, idx)
-        db = jnp.take(state.d, idx)
-        z0 = jnp.take(z_flat, idx)
-        local = jnp.clip(layout.to_physical(wb) - grp * grp_rows, 0,
-                         grp_rows - 1)
-        nwk_rows = jnp.take(rows, local, axis=0)
-        ndk_rows = jnp.take(ndk, db, axis=0)
-        aprob = jnp.take(table.prob, local, axis=0)
-        aalias = jnp.take(table.alias, local, axis=0)
-        doc_draw = lda.make_doc_draw(None, db, z_flat, state.doc_start,
-                                     state.doc_len, cfg)
-        rng = lda.draw_mh_randoms(key_g, doc_draw, gcap, cfg)
-        if cfg.use_kernels:
-            from repro.kernels import ops as kops
-            z_new = kops.mh_sample(rng, z0, nwk_rows, ndk_rows, nk, aprob,
-                                   aalias, cfg,
-                                   interpret=cfg.kernel_interpret)
-        else:
-            z_new = lda.mh_chain(rng, z0, nwk_rows, ndk_rows, nk, aprob,
-                                 aalias, cfg)
-        z_new = jnp.where(vb, z_new, z0)
+        with jax.named_scope("ps.pull"):
+            idx = gidx[grp]
+            vb = gval[grp]
+            wb = jnp.take(state.w, idx)
+            db = jnp.take(state.d, idx)
+            z0 = jnp.take(z_flat, idx)
+            local = jnp.clip(layout.to_physical(wb) - grp * grp_rows, 0,
+                             grp_rows - 1)
+            nwk_rows = jnp.take(rows, local, axis=0)
+            ndk_rows = jnp.take(ndk, db, axis=0)
+            aprob = jnp.take(table.prob, local, axis=0)
+            aalias = jnp.take(table.alias, local, axis=0)
+        with jax.named_scope("mh.chain"):
+            doc_draw = lda.make_doc_draw(None, db, z_flat, state.doc_start,
+                                         state.doc_len, cfg)
+            rng = lda.draw_mh_randoms(key_g, doc_draw, gcap, cfg)
+            if cfg.use_kernels:
+                from repro.kernels import ops as kops
+                z_new = kops.mh_sample(rng, z0, nwk_rows, ndk_rows, nk,
+                                       aprob, aalias, cfg,
+                                       interpret=cfg.kernel_interpret)
+            else:
+                z_new = lda.mh_chain(rng, z0, nwk_rows, ndk_rows, nk, aprob,
+                                     aalias, cfg)
+            z_new = jnp.where(vb, z_new, z0)
 
         # 4. group-boundary merge: the route materialises the group-local
         # delta (hot dense slice, cold COO -- whatever the policy says);
         # store_block writes the exclusively-owned rows back.
-        changed = (z_new != z0) & vb
-        d_rows = route.block_delta(
-            ps.Reassign(rows=local, words=wb, z_old=z0, z_new=z_new,
-                        changed=changed),
-            grp_rows, cfg.K, use_kernels=cfg.use_kernels,
-            interpret=cfg.kernel_interpret)
-        nwk = nwk.store_block(grp, rows + d_rows, grp_rows)
+        with jax.named_scope("ps.push"):
+            changed = (z_new != z0) & vb
+            d_rows = route.block_delta(
+                ps.Reassign(rows=local, words=wb, z_old=z0, z_new=z_new,
+                            changed=changed),
+                grp_rows, cfg.K, use_kernels=cfg.use_kernels,
+                interpret=cfg.kernel_interpret)
+            nwk = nwk.store_block(grp, rows + d_rows, grp_rows)
+            amt = changed.astype(jnp.int32)
+            nk = nk + (jnp.zeros((cfg.K,), jnp.int32)
+                       .at[z0].add(-amt).at[z_new].add(amt))
 
-        amt = changed.astype(jnp.int32)
-        nk = nk + (jnp.zeros((cfg.K,), jnp.int32)
-                   .at[z0].add(-amt).at[z_new].add(amt))
-        ndk = ndk.at[db, z0].add(-amt).at[db, z_new].add(amt)
-        z_flat = z_flat.at[idx].add(jnp.where(vb, z_new - z0, 0))
+        with jax.named_scope("ndk.merge"):
+            ndk = ndk.at[db, z0].add(-amt).at[db, z_new].add(amt)
+            z_flat = z_flat.at[idx].add(jnp.where(vb, z_new - z0, 0))
         return (nwk, nk, ndk, z_flat, pulled_next), ()
 
-    keys = jax.random.split(key, n_groups)
-    pulled0 = state.nwk.pull_block(0, grp_rows)
+    with jax.named_scope("mh.chain"):
+        keys = jax.random.split(key, n_groups)
+    with jax.named_scope("ps.pull"):
+        pulled0 = state.nwk.pull_block(0, grp_rows)
     carry = (state.nwk, state.nk.value, state.ndk, state.z, pulled0)
     (nwk, nk, ndk, z, _), _ = jax.lax.scan(
-        group_body, carry, (jnp.arange(n_groups), keys))
+        group_body, carry, (groups, keys))
     return lda.SamplerState(state.w, state.d, z, state.valid,
                             state.doc_start, state.doc_len, nwk,
                             state.nk.with_value(nk), ndk)
@@ -324,103 +335,113 @@ def snapshot_sweep(state: "lda.SamplerState", key: jax.Array,
     backend = handle.client.backend
 
     # --- snapshot "pull" (paper section 2.3 / 3.4) ---
-    snapshot = handle.pull_all().result()               # [V, K] stale counts
+    with jax.named_scope("ps.pull"):
+        snapshot = handle.pull_all().result()           # [V, K] stale counts
     nk_snap = state.nk.value                            # [K]
 
     # --- alias tables from the snapshot (paper section 3, ref [14]) ---
     # The kernel path builds them with the Pallas kernel, bitwise the
     # jnp construction: the kernel sweep stays bit-identical to the
     # oracle sweep, and at real V x K the jnp build dominates a sweep.
-    weights = (snapshot.astype(jnp.float32) + cfg.beta) / (
-        nk_snap.astype(jnp.float32)[None, :] + cfg.V * cfg.beta)
-    table = lda.build_alias_tables(weights, cfg.use_kernels,
-                                  cfg.kernel_interpret)
+    with jax.named_scope("alias.tables"):
+        weights = (snapshot.astype(jnp.float32) + cfg.beta) / (
+            nk_snap.astype(jnp.float32)[None, :] + cfg.V * cfg.beta)
+        table = lda.build_alias_tables(weights, cfg.use_kernels,
+                                       cfg.kernel_interpret)
 
-    w_groups = state.w.reshape(n_groups, gtok)
-    d_groups = state.d.reshape(n_groups, gtok)
-    v_groups = state.valid.reshape(n_groups, gtok)
+    with jax.named_scope("ps.pull"):
+        w_groups = state.w.reshape(n_groups, gtok)
+        d_groups = state.d.reshape(n_groups, gtok)
+        v_groups = state.valid.reshape(n_groups, gtok)
+        groups = jnp.arange(n_groups)
 
     def group_body(carry, inp):
         z_flat, ndk, nwk_dense, nk = carry
         grp, key_g = inp
-        w_b = w_groups[grp]
-        d_b = d_groups[grp]
-        valid_b = v_groups[grp]
-        z0 = jax.lax.dynamic_slice_in_dim(z_flat, grp * gtok, gtok)
 
         # Pre-gather per-token rows (the "pull" of the rows this group
         # needs).  The word rows come from the sweep-start snapshot; the
         # doc rows and n_k are stale by at most ``staleness`` blocks.
-        nwk_rows = jnp.take(snapshot, w_b, axis=0)
-        ndk_rows = jnp.take(ndk, d_b, axis=0)
-        aprob_rows = jnp.take(table.prob, w_b, axis=0)
-        aalias_rows = jnp.take(table.alias, w_b, axis=0)
-        doc_draw = lda.make_doc_draw(None, d_b, z_flat, state.doc_start,
-                                     state.doc_len, cfg)
-        rng = lda.draw_mh_randoms(key_g, doc_draw, gtok, cfg)
+        with jax.named_scope("ps.pull"):
+            w_b = w_groups[grp]
+            d_b = d_groups[grp]
+            valid_b = v_groups[grp]
+            z0 = jax.lax.dynamic_slice_in_dim(z_flat, grp * gtok, gtok)
+            nwk_rows = jnp.take(snapshot, w_b, axis=0)
+            ndk_rows = jnp.take(ndk, d_b, axis=0)
+            aprob_rows = jnp.take(table.prob, w_b, axis=0)
+            aalias_rows = jnp.take(table.alias, w_b, axis=0)
 
-        if cfg.use_kernels:
-            from repro.kernels import ops as kops
-            z_new = kops.mh_sample(rng, z0, nwk_rows, ndk_rows, nk,
-                                   aprob_rows, aalias_rows, cfg,
-                                   interpret=cfg.kernel_interpret)
-        else:
-            z_new = lda.mh_chain(rng, z0, nwk_rows, ndk_rows, nk,
-                                 aprob_rows, aalias_rows, cfg)
-        z_new = jnp.where(valid_b, z_new, z0)
+        with jax.named_scope("mh.chain"):
+            doc_draw = lda.make_doc_draw(None, d_b, z_flat, state.doc_start,
+                                         state.doc_len, cfg)
+            rng = lda.draw_mh_randoms(key_g, doc_draw, gtok, cfg)
+            if cfg.use_kernels:
+                from repro.kernels import ops as kops
+                z_new = kops.mh_sample(rng, z0, nwk_rows, ndk_rows, nk,
+                                       aprob_rows, aalias_rows, cfg,
+                                       interpret=cfg.kernel_interpret)
+            else:
+                z_new = lda.mh_chain(rng, z0, nwk_rows, ndk_rows, nk,
+                                     aprob_rows, aalias_rows, cfg)
+            z_new = jnp.where(valid_b, z_new, z0)
 
         # --- routed delta aggregation + group-boundary merge (3.3) ---
-        changed = (z0 != z_new) & valid_b
-        plan = route.plan(
-            ps.Reassign(rows=w_b, words=w_b, z_old=z0, z_new=z_new,
-                        changed=changed),
-            cfg.V, cfg.K, use_kernels=cfg.use_kernels, prefix_rows=True,
-            interpret=cfg.kernel_interpret)
-        amt = changed.astype(jnp.int32)
-        d_nk = (jnp.zeros((cfg.K,), jnp.int32)
-                .at[z0].add(-amt).at[z_new].add(amt))
-        # SPMD "push": merge each half of the plan over the workers once
-        # per group (identity in-process).  The dense part -- the
-        # hybrid's [H, K] hot prefix, never padded to [V, K] -- sums
-        # elementwise and lands on the first H rows; the coordinate part
-        # stays compressed, the workers' buffers are concatenated and
-        # every entry scatter-applied once.  Int adds commute, so the
-        # merged counts are bitwise those of the dense formulation.
-        if plan.dense is not None:
-            d = backend.reduce(plan.dense)
-            h = d.shape[0]
-            if h < cfg.V:
-                nwk_dense = nwk_dense.at[:h, :].add(d)
-            else:
-                nwk_dense = nwk_dense + d
-        if plan.coo is not None:
-            c_rows, c_cols, c_vals = (backend.gather_concat(x)
-                                      for x in plan.coo)
-            if route.coo_kernel(cfg.use_kernels):
-                from repro.kernels import ops as kops
-                nwk_dense = nwk_dense + kops.delta_apply_coo(
-                    c_rows, c_cols, c_vals, cfg.V, cfg.K,
-                    interpret=cfg.kernel_interpret)
-            else:
-                safe = jnp.clip(c_rows, 0, cfg.V - 1)
-                nwk_dense = nwk_dense.at[safe, c_cols].add(c_vals)
-        d_nk = backend.reduce(d_nk)
+        with jax.named_scope("ps.push"):
+            changed = (z0 != z_new) & valid_b
+            plan = route.plan(
+                ps.Reassign(rows=w_b, words=w_b, z_old=z0, z_new=z_new,
+                            changed=changed),
+                cfg.V, cfg.K, use_kernels=cfg.use_kernels, prefix_rows=True,
+                interpret=cfg.kernel_interpret)
+            amt = changed.astype(jnp.int32)
+            d_nk = (jnp.zeros((cfg.K,), jnp.int32)
+                    .at[z0].add(-amt).at[z_new].add(amt))
+            # SPMD "push": merge each half of the plan over the workers
+            # once per group (identity in-process).  The dense part -- the
+            # hybrid's [H, K] hot prefix, never padded to [V, K] -- sums
+            # elementwise and lands on the first H rows; the coordinate
+            # part stays compressed, the workers' buffers are concatenated
+            # and every entry scatter-applied once.  Int adds commute, so
+            # the merged counts are bitwise those of the dense formulation.
+            if plan.dense is not None:
+                d = backend.reduce(plan.dense)
+                h = d.shape[0]
+                if h < cfg.V:
+                    nwk_dense = nwk_dense.at[:h, :].add(d)
+                else:
+                    nwk_dense = nwk_dense + d
+            if plan.coo is not None:
+                c_rows, c_cols, c_vals = (backend.gather_concat(x)
+                                          for x in plan.coo)
+                if route.coo_kernel(cfg.use_kernels):
+                    from repro.kernels import ops as kops
+                    nwk_dense = nwk_dense + kops.delta_apply_coo(
+                        c_rows, c_cols, c_vals, cfg.V, cfg.K,
+                        interpret=cfg.kernel_interpret)
+                else:
+                    safe = jnp.clip(c_rows, 0, cfg.V - 1)
+                    nwk_dense = nwk_dense.at[safe, c_cols].add(c_vals)
+            nk = nk + backend.reduce(d_nk)
+
         # n_dk stays local: docs are owned by one worker (paper sec. 3),
         # and merges in place -- never through a [D, K] delta per group.
-        ndk = ndk.at[d_b, z0].add(-amt).at[d_b, z_new].add(amt)
+        with jax.named_scope("ndk.merge"):
+            ndk = ndk.at[d_b, z0].add(-amt).at[d_b, z_new].add(amt)
+            z_flat = jax.lax.dynamic_update_slice_in_dim(
+                z_flat, z_new, grp * gtok, axis=0)
+        return (z_flat, ndk, nwk_dense, nk), ()
 
-        z_flat = jax.lax.dynamic_update_slice_in_dim(
-            z_flat, z_new, grp * gtok, axis=0)
-        return (z_flat, ndk, nwk_dense, nk + d_nk), ()
-
-    keys = jax.random.split(key, n_groups)
+    with jax.named_scope("mh.chain"):
+        keys = jax.random.split(key, n_groups)
     carry = (state.z, state.ndk, snapshot, nk_snap)
     (z, ndk, nwk_dense, nk), _ = jax.lax.scan(
-        group_body, carry, (jnp.arange(n_groups), keys))
+        group_body, carry, (groups, keys))
 
     # --- write back to the server layout (SPMD keeps only own rows) ---
-    new_nwk = handle.client.matrix_from_dense(
-        nwk_dense, route=handle.route).localize()
+    with jax.named_scope("ps.push"):
+        new_nwk = handle.client.matrix_from_dense(
+            nwk_dense, route=handle.route).localize()
     return lda.SamplerState(state.w, state.d, z, state.valid,
                             state.doc_start, state.doc_len, new_nwk,
                             state.nk.with_value(nk), ndk)
@@ -430,41 +451,56 @@ def snapshot_sweep(state: "lda.SamplerState", key: jax.Array,
 # Host-side factory: what the launchers and train.loop.fit_lda drive.
 # ---------------------------------------------------------------------------
 
+def _jit_as(name: str, fn):
+    """``jax.jit(fn)`` under a stable name: the compiled program is
+    ``jit_<name>`` in HLO, traces and the ``obs.scopes`` registry."""
+    fn.__name__ = name
+    return jax.jit(fn)
+
+
 def _obs_step(jit_step, exec_cfg: ExecConfig, info: dict):
     """Wrap a jitted sweep step with host-side sweep spans.
 
-    Per sweep, when an obs session is installed: ``exec.dispatch`` (the
-    host enqueue window -- jit call issued, control returned),
+    Per sweep: ``exec.dispatch`` (the host enqueue window -- jit call
+    issued, control returned), a profiler annotation whenever a jax
+    profiler is recording; and, when an obs session is installed,
     ``exec.sweep`` (dispatch + device completion, closed by an explicit
-    ``block_until_ready`` on the new state's ``z``), and a ``[device]``
-    lane span for the remainder, so the Perfetto timeline shows how much
-    of each sweep the host was free (the async overlap window).  The
-    *overlap efficiency* is ``1 - dispatch/total``.
+    ``block_until_ready`` on the new state's ``z``).  The *overlap
+    efficiency* is ``1 - dispatch/total``.
 
-    With no session installed the wrapper costs one attribute read and
-    one ``is None`` test per sweep -- the <1% bar ``bench_obs.py``
-    asserts.  The unwrapped step stays reachable as ``step.raw``.  The
-    sync only ever awaits values the caller would consume anyway; the
-    sampled state is bitwise identical with tracing on or off.
+    The first call registers the jitted step with ``obs.scopes`` under
+    its name, with that call's abstract arguments, so a profiler trace's
+    device operations can be read by sweep phase.  With no session
+    installed the wrapper costs a flag test, one attribute read, one
+    ``is None`` test and an annotation check per sweep -- the <1% bar
+    ``bench_obs.py`` asserts.  The unwrapped step stays reachable as
+    ``step.raw``.  The sync only ever awaits values the caller would
+    consume anyway; the sampled state is bitwise identical with tracing
+    on or off.
     """
+    registered = []
 
     def step(st, key, *rest):
+        if not registered:
+            registered.append(True)
+            if hasattr(jit_step, "lower"):
+                _scopes.register(jit_step.__name__, jit_step,
+                                 (st, key) + rest)
         tr = _obs.tracer_for(exec_cfg.obs)
         if tr is None:
-            return jit_step(st, key, *rest)
+            with _obs.annotation("exec.dispatch"):
+                return jit_step(st, key, *rest)
         t0 = time.perf_counter_ns()
-        out = jit_step(st, key, *rest)
+        with tr.span("exec.dispatch", cat="exec", mode=info["mode"]):
+            out = jit_step(st, key, *rest)
         t1 = time.perf_counter_ns()
         _block(out.z)
         t2 = time.perf_counter_ns()
         overlap = 1.0 - (t1 - t0) / max(t2 - t0, 1)
-        tr.complete("exec.dispatch", t0, t1, cat="exec", mode=info["mode"])
         tr.complete("exec.sweep", t0, t2, cat="exec", mode=info["mode"],
                     staleness=info["staleness"], group=info.get("group"),
                     route=info["route"],
                     overlap_pct=round(overlap * 100.0, 2))
-        tr.complete("sweep.device", t1, t2, cat="device",
-                    tid=tr.lane("device"))
         reg = _obs.metrics_for(exec_cfg.obs)
         if reg is not None:
             reg.histogram("exec.sweep_ms").record((t2 - t0) / 1e6)
@@ -516,8 +552,9 @@ def make_stream_executor(cfg: "lda.LDAConfig", exec_cfg: ExecConfig,
                                             exec_cfg.staleness)
         rpb_step = rpb * (s + 1)
 
-        step = jax.jit(lambda st, k, idx, bval: pipelined_sweep(
-            st, k, cfg, idx, bval, rpb_step, staleness=0, route=route))
+        step = _jit_as("pipelined_sweep", lambda st, k, idx, bval:
+                       pipelined_sweep(st, k, cfg, idx, bval, rpb_step,
+                                       staleness=0, route=route))
 
         def build_index(w, valid, cap=None):
             idx, bval = lda.block_token_index(
@@ -532,7 +569,7 @@ def make_stream_executor(cfg: "lda.LDAConfig", exec_cfg: ExecConfig,
                 "hot_words": exec_cfg.hot_words, "route": repr(route)}
         return _obs_step(step, exec_cfg, info), build_index, info
 
-    jit_step = jax.jit(lambda st, k: snapshot_sweep(
+    jit_step = _jit_as("snapshot_sweep", lambda st, k: snapshot_sweep(
         st, k, cfg, staleness=exec_cfg.staleness, route=route))
     info = {"mode": "snapshot", "n_blocks": None, "rows_per_block": None,
             "staleness": exec_cfg.staleness,
@@ -572,7 +609,7 @@ def make_executor(state: "lda.SamplerState", cfg: "lda.LDAConfig",
         idx, bval = lda.block_token_index(
             np.asarray(state.w), np.asarray(state.valid), rpb_step, layout)
         idx, bval = jnp.asarray(idx), jnp.asarray(bval)
-        step = jax.jit(lambda st, k: pipelined_sweep(
+        step = _jit_as("pipelined_sweep", lambda st, k: pipelined_sweep(
             st, k, cfg, idx, bval, rpb_step, staleness=0, route=route))
         info = {"mode": "blocked", "n_blocks": n_blocks,
                 "rows_per_block": rpb, "staleness": s,
@@ -583,7 +620,7 @@ def make_executor(state: "lda.SamplerState", cfg: "lda.LDAConfig",
         n = state.w.shape[0]
         n_blocks = n // cfg.block_tokens
         s = effective_staleness(n_blocks, exec_cfg.staleness)
-        step = jax.jit(lambda st, k: snapshot_sweep(
+        step = _jit_as("snapshot_sweep", lambda st, k: snapshot_sweep(
             st, k, cfg, staleness=exec_cfg.staleness, route=route))
         info = {"mode": "snapshot", "n_blocks": n_blocks,
                 "rows_per_block": None, "staleness": s, "group": s + 1,
@@ -680,43 +717,49 @@ def make_tiered_executor(state: "lda.SamplerState", cfg: "lda.LDAConfig",
         # with the block offset a traced scalar so every block of one
         # capacity shares a single compiled trace
         cap = idx.shape[0]
-        weights = (rows.astype(jnp.float32) + cfg.beta) / (
-            nk.astype(jnp.float32)[None, :] + cfg.V * cfg.beta)
-        table = lda.build_alias_tables(weights, cfg.use_kernels,
-                                  cfg.kernel_interpret)
-        wb = jnp.take(w_dev, idx)
-        db = jnp.take(d_dev, idx)
-        z0 = jnp.take(z_flat, idx)
-        local = jnp.clip(wb - start, 0, rpb - 1)
-        nwk_rows = jnp.take(rows, local, axis=0)
-        ndk_rows = jnp.take(ndk, db, axis=0)
-        aprob = jnp.take(table.prob, local, axis=0)
-        aalias = jnp.take(table.alias, local, axis=0)
-        doc_draw = lda.make_doc_draw(None, db, z_flat, doc_start, doc_len,
-                                     cfg)
-        rng = lda.draw_mh_randoms(key_b, doc_draw, cap, cfg)
-        if cfg.use_kernels:
-            from repro.kernels import ops as kops
-            z_new = kops.mh_sample(rng, z0, nwk_rows, ndk_rows, nk, aprob,
-                                   aalias, cfg,
-                                   interpret=cfg.kernel_interpret)
-        else:
-            z_new = lda.mh_chain(rng, z0, nwk_rows, ndk_rows, nk, aprob,
-                                 aalias, cfg)
-        z_new = jnp.where(bval, z_new, z0)
-        changed = (z_new != z0) & bval
-        d_rows = route.block_delta(
-            ps.Reassign(rows=local, words=wb, z_old=z0, z_new=z_new,
-                        changed=changed),
-            rpb, cfg.K, use_kernels=cfg.use_kernels,
-            interpret=cfg.kernel_interpret)
-        amt = changed.astype(jnp.int32)
-        nk2 = nk + (jnp.zeros((cfg.K,), jnp.int32)
-                    .at[z0].add(-amt).at[z_new].add(amt))
-        ndk2 = ndk.at[db, z0].add(-amt).at[db, z_new].add(amt)
-        z2 = z_flat.at[idx].add(jnp.where(bval, z_new - z0, 0))
-        rtraf = jnp.zeros((rpb,), jnp.int32).at[local].add(amt)
-        return rows + d_rows, nk2, ndk2, z2, rtraf
+        with jax.named_scope("alias.tables"):
+            weights = (rows.astype(jnp.float32) + cfg.beta) / (
+                nk.astype(jnp.float32)[None, :] + cfg.V * cfg.beta)
+            table = lda.build_alias_tables(weights, cfg.use_kernels,
+                                           cfg.kernel_interpret)
+        with jax.named_scope("ps.pull"):
+            wb = jnp.take(w_dev, idx)
+            db = jnp.take(d_dev, idx)
+            z0 = jnp.take(z_flat, idx)
+            local = jnp.clip(wb - start, 0, rpb - 1)
+            nwk_rows = jnp.take(rows, local, axis=0)
+            ndk_rows = jnp.take(ndk, db, axis=0)
+            aprob = jnp.take(table.prob, local, axis=0)
+            aalias = jnp.take(table.alias, local, axis=0)
+        with jax.named_scope("mh.chain"):
+            doc_draw = lda.make_doc_draw(None, db, z_flat, doc_start,
+                                         doc_len, cfg)
+            rng = lda.draw_mh_randoms(key_b, doc_draw, cap, cfg)
+            if cfg.use_kernels:
+                from repro.kernels import ops as kops
+                z_new = kops.mh_sample(rng, z0, nwk_rows, ndk_rows, nk,
+                                       aprob, aalias, cfg,
+                                       interpret=cfg.kernel_interpret)
+            else:
+                z_new = lda.mh_chain(rng, z0, nwk_rows, ndk_rows, nk, aprob,
+                                     aalias, cfg)
+            z_new = jnp.where(bval, z_new, z0)
+        with jax.named_scope("ps.push"):
+            changed = (z_new != z0) & bval
+            d_rows = route.block_delta(
+                ps.Reassign(rows=local, words=wb, z_old=z0, z_new=z_new,
+                            changed=changed),
+                rpb, cfg.K, use_kernels=cfg.use_kernels,
+                interpret=cfg.kernel_interpret)
+            amt = changed.astype(jnp.int32)
+            nk2 = nk + (jnp.zeros((cfg.K,), jnp.int32)
+                        .at[z0].add(-amt).at[z_new].add(amt))
+            rtraf = jnp.zeros((rpb,), jnp.int32).at[local].add(amt)
+            rows2 = rows + d_rows
+        with jax.named_scope("ndk.merge"):
+            ndk2 = ndk.at[db, z0].add(-amt).at[db, z_new].add(amt)
+            z2 = z_flat.at[idx].add(jnp.where(bval, z_new - z0, 0))
+        return rows2, nk2, ndk2, z2, rtraf
 
     sweep_count = [0]
 

@@ -6,7 +6,8 @@ disabled, for both the in-memory and the streamed planes, and a disabled
 run writes no files at all.  Around that: the tracer's Chrome-trace
 output, the HDR histogram's percentile error bound, the under-jit no-op
 guard, the instrumented subsystems (ps.push routes, engine serving,
-stream loader), the eager executor replay, the obs_report renderer, and
+stream loader), spans on the profiler's clock, the sweep's phase scopes
+and the benchmark's readers of them, the obs_report renderer, and
 the satellite regressions (LogCallback timestamps/flush, fit_lda
 deprecation warnings).
 """
@@ -20,7 +21,7 @@ import numpy as np
 import pytest
 
 from repro import obs
-from repro.obs.trace import LANE_BASE, NULL_SPAN, Tracer
+from repro.obs.trace import NULL_SPAN, Tracer
 
 
 # ---------------------------------------------------------------------------
@@ -71,18 +72,19 @@ def test_registry_jsonl_roundtrip(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# tracer: Chrome-trace JSON, lanes, thread metadata
+# tracer: Chrome-trace JSON, thread metadata
 # ---------------------------------------------------------------------------
 
 def test_tracer_chrome_trace_output(tmp_path):
+    import threading
     import time
 
     tr = Tracer()
     with tr.span("outer", cat="test", foo=1) as sp:
         time.sleep(0.005)
         sp.set(bar=2)
-    tr.complete("lane_ev", time.perf_counter_ns() - 2_000_000,
-                time.perf_counter_ns(), cat="pull", tid=tr.lane("pull"))
+    tr.complete("measured", time.perf_counter_ns() - 2_000_000,
+                time.perf_counter_ns(), cat="pull", rows=3)
     tr.instant("mark", cat="test")
     path = str(tmp_path / "t.json")
     tr.save(path)
@@ -93,9 +95,12 @@ def test_tracer_chrome_trace_output(tmp_path):
     spans = {e["name"]: e for e in events if e.get("ph") == "X"}
     assert spans["outer"]["dur"] >= 4000            # us; slept 5ms
     assert spans["outer"]["args"] == {"foo": 1, "bar": 2}
-    assert spans["lane_ev"]["tid"] >= LANE_BASE
+    assert spans["measured"]["dur"] >= 2000
+    assert spans["measured"]["args"] == {"rows": 3}
+    assert spans["measured"]["tid"] == spans["outer"]["tid"]
     metas = [e for e in events if e.get("ph") == "M"]
-    assert any(e["args"]["name"] == "[pull]" for e in metas)
+    assert [e["args"]["name"] for e in metas] == [
+        threading.current_thread().name]
     assert any(e.get("ph") == "i" and e["name"] == "mark" for e in events)
 
 
@@ -330,41 +335,240 @@ def test_loader_prefetch_counters(stream_dir):
 
 
 # ---------------------------------------------------------------------------
-# eager executor replay (obs.exec_trace)
+# spans on the profiler's clock
 # ---------------------------------------------------------------------------
 
-def test_exec_trace_replay_matches_executor(lda_state):
+def _profiled_host_events(trace_dir, body):
+    """Run ``body`` under ``jax.profiler`` and return the host events of
+    the ``.xplane.pb`` it writes: {name: [(start_ns, end_ns)]}."""
+    import glob
+
     import jax
-    from repro.obs import exec_trace
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(os.path.join(str(trace_dir), "**",
+                                         "*.xplane.pb"), recursive=True))[-1]
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    events.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.end_ns))
+    return events
+
+
+@pytest.mark.parametrize("installed", [False, True])
+def test_span_lands_in_the_profiler_trace_inside_its_caller(tmp_path,
+                                                            installed):
+    import jax
+
+    def body():
+        with jax.profiler.TraceAnnotation("caller"):
+            with obs.span("test.inner", cat="test"):
+                jax.numpy.arange(8).block_until_ready()
+
+    sess = (obs.ObsSession(obs.ObsConfig(enabled=True)).install()
+            if installed else None)
+    try:
+        events = _profiled_host_events(tmp_path, body)
+    finally:
+        if sess is not None:
+            sess.close(save=False)
+    (cs, ce), = events["caller"]
+    (s, e), = events["test.inner"]
+    assert cs <= s <= e <= ce
+    if installed:                      # and the Chrome span as before
+        assert [ev["name"] for ev in sess.tracer.events()
+                if ev.get("ph") == "X"] == ["test.inner"]
+
+
+def test_no_profiler_no_session_span_is_null():
+    assert obs.annotation("x") is NULL_SPAN
+    assert obs.span("x") is NULL_SPAN
+
+
+def test_executor_dispatch_is_annotated_without_a_session(tmp_path,
+                                                          lda_state):
+    import jax
     from repro.train import async_exec
 
     _, cfg, state = lda_state(num_docs=80, vocab=128, k=8, num_shards=2,
                               block_tokens=256)
-    blocks, staleness = 4, 1
-    step, _ = async_exec.make_executor(
-        state, cfg, async_exec.ExecConfig(staleness=staleness,
-                                          model_blocks=blocks))
-    key = jax.random.PRNGKey(3)
-    want = step.raw(state, key)
+    step, _ = async_exec.make_executor(state, cfg, async_exec.ExecConfig())
+    jax.block_until_ready(step(state, jax.random.PRNGKey(0)).z)   # compile
+    assert obs.active() is None
+    events = _profiled_host_events(
+        tmp_path, lambda: jax.block_until_ready(
+            step(state, jax.random.PRNGKey(1)).z))
+    assert len(events["exec.dispatch"]) == 1
 
-    s = obs.ObsSession(obs.ObsConfig(enabled=True)).install()
-    try:
-        got = exec_trace.traced_pipelined_sweep(
-            state, key, cfg, model_blocks=blocks, staleness=staleness)
-        names = {e["name"] for e in s.tracer.events()
-                 if e.get("ph") == "X"}
-        assert {"pull.inflight", "alias.build", "sample",
-                "merge.store"} <= names
-        pulls = [e for e in s.tracer.events()
-                 if e.get("ph") == "X" and e["name"] == "pull.inflight"]
-        assert all(e["tid"] >= LANE_BASE for e in pulls)
-    finally:
-        s.close(save=False)
-    np.testing.assert_array_equal(np.asarray(got.z), np.asarray(want.z))
-    np.testing.assert_array_equal(np.asarray(got.nwk.to_dense()),
-                                  np.asarray(want.nwk.to_dense()))
-    np.testing.assert_array_equal(np.asarray(got.nk.value),
-                                  np.asarray(want.nk.value))
+
+# ---------------------------------------------------------------------------
+# the sweep's phases: scope table and the benchmark's readers of it
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("blocks", [0, 4])
+def test_executor_registers_its_step_once_by_name(lda_state, monkeypatch,
+                                                  blocks):
+    import jax
+    from repro.obs import scopes
+    from repro.train import async_exec
+
+    monkeypatch.setattr(scopes, "_PROGRAMS", [])
+    _, cfg, state = lda_state(num_docs=80, vocab=128, k=8, num_shards=2,
+                              block_tokens=256)
+    step, _ = async_exec.make_executor(
+        state, cfg, async_exec.ExecConfig(model_blocks=blocks))
+    for i in range(2):
+        state = step(state, jax.random.PRNGKey(i))
+    name = "pipelined_sweep" if blocks else "snapshot_sweep"
+    assert scopes.registered() == [name]
+    table = scopes.scope_table()
+    assert set(table.values()) == set(scopes.PHASES) | {None}
+
+
+def test_scope_table_marks_names_two_programs_place_differently(
+        monkeypatch):
+    from repro.obs import scopes
+
+    monkeypatch.setattr(scopes, "_PROGRAMS", [
+        {"name": "a", "fn": None, "args": (),
+         "table": {"fusion.1": "ps.pull", "add.2": "mh.chain",
+                   "copy.3": None, "copy.4": None}},
+        {"name": "b", "fn": None, "args": (),
+         "table": {"fusion.1": "mh.chain", "add.2": "mh.chain",
+                   "copy.3": None, "copy.4": "ps.push", "sort.5": None}}])
+    assert scopes.scope_table() == {
+        "fusion.1": scopes.AMBIGUOUS, "add.2": "mh.chain", "copy.3": None,
+        "copy.4": scopes.AMBIGUOUS, "sort.5": None}
+
+
+SCOPED_HLO = """HloModule jit_f
+
+%fused_computation.1 (param_0: s32[8]) -> s32[64] {
+  %param_0 = s32[8]{0} parameter(0)
+  %negate.1 = s32[8]{0} negate(%param_0), metadata={op_name="jit(f)/while/body/ndk.merge/neg"}
+  ROOT %scatter.1 = s32[64]{0} scatter(%param_0, %negate.1), to_apply=%add
+}
+
+ENTRY %main.9 (p0: s32[8], p1: s32[8]) -> (s32[64], s32[8]) {
+  %p0 = s32[8]{0} parameter(0)
+  %p1 = s32[8]{0} parameter(1)
+  %fusion.1 = s32[64]{0} fusion(%p0), kind=kCustom, calls=%fused_computation.1
+  %copy.1 = s32[64]{0} copy(%fusion.1)
+  %slice-start.1 = ((s32[8]{0}), s32[8]{0}, s32[]) slice-start(%p1), slice={[0:8]}
+  %slice-done.1 = s32[8]{0} slice-done(%slice-start.1)
+  %add.1 = s32[8]{0} add(%slice-done.1, %p0), metadata={op_name="jit(f)/ps.push/add"}
+  %add.2 = s32[8]{0} add(%p0, %p1), metadata={op_name="jit(f)/while/body/add"}
+  %select.1 = s32[8]{0} select(%add.1, %negate.2, %p0)
+  %negate.2 = s32[8]{0} negate(%p1), metadata={op_name="jit(f)/mh.chain/neg"}
+  ROOT %tuple.1 = (s32[64]{0}, s32[8]{0}) tuple(%copy.1, %add.1)
+}
+"""
+
+
+@pytest.mark.parametrize("name,phase", [
+    ("negate.1", "ndk.merge"),       # its own op_name
+    ("fusion.1", "ndk.merge"),       # the root of the computation it calls
+    ("copy.1", "ndk.merge"),         # its operand
+    ("slice-done.1", "ps.push"),     # its user
+    ("slice-start.1", "ps.push"),    # its user's user
+    ("add.2", None),                 # an op_name in no phase
+    ("tuple.1", None),               # gathers values of every phase
+])
+def test_instruction_phase_rules(name, phase):
+    from repro.obs import scopes
+
+    got = {i.name: i for i in scopes.instructions(SCOPED_HLO)}
+    assert got[name].phase == phase
+    assert got["fusion.1"].computation == "main.9"
+    assert got["scatter.1"].computation == "fused_computation.1"
+
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# what each reader reads from SWEEP_OPS plus 0.0175 s of an operation the
+# table does not know (under 1%), over 2 sweeps
+PHASE_READERS = {"ps_pull_s.train": 0.05, "alias_tables_s.train": 0.1,
+                 "mh_chain_s.train": 0.15, "ps_push_s.train": 0.3,
+                 "ndk_merge_s.train": 0.2,
+                 "unscoped_share.train": 100.0 * 0.2 / 1.8175}
+
+
+def _run_with_ops(monkeypatch, ops):
+    """A traced run whose device plane holds ``ops`` ({text: seconds})
+    over 2 sweeps, and whose program's scope table is fixed below."""
+    import types
+
+    from repro.obs import scopes
+
+    monkeypatch.syspath_prepend(os.path.join(REPO, "perfbench"))
+    import tracing
+    monkeypatch.setattr(scopes, "scope_table", lambda: {
+        "pull.1": "ps.pull", "alias_build.1": "alias.tables",
+        "mh_sample.1": "mh.chain", "fusion.168": "ps.push",
+        "fusion.165": "ndk.merge", "copy.70": None,
+        "fusion.9": scopes.AMBIGUOUS})
+    summary = tracing.TraceSummary(
+        (0, 2 * 10**9),
+        {"/device:TPU:0": {"busy": [(0, 10**9)],
+                           "ops": {k: v * 1e9 for k, v in ops.items()}}},
+        [])
+    return types.SimpleNamespace(trace=summary, counters={"sweeps": 2})
+
+
+def _read(name, run):
+    import importlib.util
+
+    path = os.path.join(REPO, "perfbench", "metrics", name + ".py")
+    spec = importlib.util.spec_from_file_location(
+        "test_reader_" + name.replace(".", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read(run)
+
+
+SWEEP_OPS = {"%pull.1 = s32[8,8]{1,0} fusion(%a)": 0.1,
+             "%alias_build.1 = (f32[8,8]{1,0}, s32[8,8]{1,0}) "
+             "custom-call(%a), custom_call_target=\"tpu_custom_call\"": 0.2,
+             "%mh_sample.1 = s32[1,8]{1,0} custom-call(%a)": 0.3,
+             "%fusion.168 = s32[64]{0} fusion(%a)": 0.6,
+             "%fusion.165 = s32[64]{0} fusion(%a)": 0.4,
+             "%copy.70 = s32[64]{0} copy(%a)": 0.2}
+
+
+@pytest.mark.parametrize("name", sorted(PHASE_READERS))
+def test_phase_readers_read_seconds_per_sweep(monkeypatch, name):
+    ops = {**SWEEP_OPS, "%mystery.1 = s32[8]{0} add(%a, %b)": 0.0175}
+    got = _read(name, _run_with_ops(monkeypatch, ops))
+    # under 1% of the operation time unknown to the table: still read
+    assert got == pytest.approx(PHASE_READERS[name], rel=1e-9)
+
+
+@pytest.mark.parametrize("unknown", ["%mystery.1 = s32[8]{0} add(%a, %b)",
+                                     "%fusion.9 = s32[8]{0} fusion(%a)",
+                                     "not an instruction"])
+def test_phase_readers_read_nothing_past_one_percent_unmatched(
+        monkeypatch, unknown):
+    run = _run_with_ops(monkeypatch, {**SWEEP_OPS, unknown: 0.03})
+    for name in PHASE_READERS:
+        assert _read(name, run) is None, name
+
+
+def test_phase_readers_read_nothing_from_a_program_without_scopes(
+        monkeypatch):
+    import sys
+
+    run = _run_with_ops(monkeypatch, SWEEP_OPS)
+    monkeypatch.delattr(obs, "scopes", raising=False)
+    monkeypatch.setitem(sys.modules, "repro.obs.scopes", None)
+    for name in PHASE_READERS:
+        assert _read(name, run) is None, name
 
 
 # ---------------------------------------------------------------------------

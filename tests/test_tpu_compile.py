@@ -11,6 +11,7 @@ only one process may load the TPU library, and every test worker imports
 every test file.  All rehearsals stay in this one file for the same reason.
 """
 import os
+import re
 
 import pytest
 
@@ -20,6 +21,7 @@ import jax.numpy as jnp
 from repro.kernels import alias_build, delta_push, mh_sample
 
 V_NYT = 102_660          # NYTimes vocabulary (the serving alias build's rows)
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(scope="module")
@@ -52,10 +54,8 @@ def _spec(sharding):
                                                   sharding=sharding)
 
 
-@pytest.mark.parametrize("frozen", [False, True])
-@pytest.mark.parametrize("k", [128, 1024])
-def test_mh_sample_compiles(one_chip, k, frozen):
-    s = _spec(one_chip)
+def _mh_sample(sharding, k, frozen):
+    s = _spec(sharding)
     b = 2048
     f32, i32 = jnp.float32, jnp.int32
 
@@ -64,41 +64,178 @@ def test_mh_sample_compiles(one_chip, k, frozen):
             *a, num_topics=k, vocab_size=V_NYT, alpha=0.1, beta=0.01,
             mh_steps=2, interpret=False, frozen=frozen)
 
-    _compile(fn, s((1, b), i32), s((b, k), f32), s((b, k), f32),
-             s((1, k), f32), s((b, k), f32), s((b, k), i32),
-             s((2, b), f32), s((2, b), f32), s((2, b), i32), s((2, b), f32))
+    return _compile(fn, s((1, b), i32), s((b, k), f32), s((b, k), f32),
+                    s((1, k), f32), s((b, k), f32), s((b, k), i32),
+                    s((2, b), f32), s((2, b), f32), s((2, b), i32),
+                    s((2, b), f32))
 
 
-@pytest.mark.parametrize("k", [128, 1024])
-def test_alias_build_compiles(one_chip, k):
-    s = _spec(one_chip)
+def _alias_build(sharding, k):
+    s = _spec(sharding)
     v = 1024
     f32, i32 = jnp.float32, jnp.int32
 
     def fn(*a):
         return alias_build.alias_build_call(*a, num_cols=k, interpret=False)
 
-    _compile(fn, s((v, k), f32), s((v, k), i32), s((v, k), i32),
-             s((v, 1), i32), s((v, 1), i32))
+    return _compile(fn, s((v, k), f32), s((v, k), i32), s((v, k), i32),
+                    s((v, 1), i32), s((v, 1), i32))
 
 
-def test_delta_push_compiles(one_chip):
-    s = _spec(one_chip)
+def _delta_push(sharding):
+    s = _spec(sharding)
     tok = s((1, 8192), jnp.int32)
 
     def fn(*a):
         return delta_push.delta_push_call(*a, vocab_pad=2048, k_pad=1024,
                                           interpret=False)
 
-    _compile(fn, tok, tok, tok, tok)
+    return _compile(fn, tok, tok, tok, tok)
 
 
-def test_delta_apply_coo_compiles(one_chip):
-    s = _spec(one_chip)
+def _delta_apply_coo(sharding):
+    s = _spec(sharding)
     tok = s((1, 16384), jnp.int32)
 
     def fn(*a):
         return delta_push.delta_apply_coo_call(*a, vocab_pad=2048,
                                                k_pad=1024, interpret=False)
 
-    _compile(fn, tok, tok, tok)
+    return _compile(fn, tok, tok, tok)
+
+
+@pytest.mark.parametrize("frozen", [False, True])
+@pytest.mark.parametrize("k", [128, 1024])
+def test_mh_sample_compiles(one_chip, k, frozen):
+    _mh_sample(one_chip, k, frozen)
+
+
+@pytest.mark.parametrize("k", [128, 1024])
+def test_alias_build_compiles(one_chip, k):
+    _alias_build(one_chip, k)
+
+
+def test_delta_push_compiles(one_chip):
+    _delta_push(one_chip)
+
+
+def test_delta_apply_coo_compiles(one_chip):
+    _delta_apply_coo(one_chip)
+
+
+# ---------------------------------------------------------------------------
+# Names a profiler trace reads: kernel names and the sweep's phase scopes
+# ---------------------------------------------------------------------------
+
+def _custom_calls(text):
+    """The tpu_custom_call instruction lines, as a trace names them
+    (``%name = shape custom-call(...)``)."""
+    return [ln.strip().removeprefix("ROOT ") for ln in text.splitlines()
+            if "custom-call(" in ln and "tpu_custom_call" in ln]
+
+
+@pytest.mark.parametrize("name,build", [
+    ("mh_sample", lambda c: _mh_sample(c, 128, False)),
+    ("mh_sample_frozen", lambda c: _mh_sample(c, 128, True)),
+    ("alias_build", lambda c: _alias_build(c, 128)),
+    ("delta_push", _delta_push),
+    ("delta_apply_coo", _delta_apply_coo),
+])
+def test_kernel_instruction_carries_its_name(one_chip, name, build):
+    calls = _custom_calls(build(one_chip).as_text())
+    assert len(calls) == 1, calls
+    assert re.match(rf"^%{name}(\.\d+)? = ", calls[0]), calls[0][:120]
+
+
+SWEEP_K = 1024
+
+
+@pytest.fixture(scope="module")
+def sweeps(one_chip):
+    """Small snapshot and pipelined sweeps at K=1,024 (4 blocks), Pallas
+    path and hybrid push as the training cells run them, compiled for the
+    described chip: ``{kind: (jitted step, abstract args, HLO text)}``."""
+    import numpy as np
+
+    from repro import ps
+    from repro.core import lightlda as lda
+    from repro.data import corpus as corpus_mod
+    from repro.train import async_exec
+
+    corp = corpus_mod.generate_lda_corpus(
+        seed=0, num_docs=200, mean_doc_len=40, vocab_size=4096,
+        num_topics=8)
+    cfg = lda.LDAConfig(num_topics=SWEEP_K, vocab_size=4096,
+                        block_tokens=2048, use_kernels=True,
+                        kernel_interpret=False)
+    n = int(corp.w.shape[0])
+    st = jax.eval_shape(
+        lambda w, d: lda.init_state(jax.random.PRNGKey(0), w, d,
+                                    corp.num_docs, cfg),
+        jax.ShapeDtypeStruct((n,), jnp.int32),
+        jax.ShapeDtypeStruct((n,), jnp.int32))
+    spec = _spec(one_chip)
+    state = jax.tree.map(lambda x: spec(x.shape, x.dtype), st)
+    key = spec((2,), jnp.uint32)
+    route = ps.HybridRoute(hot_words=512, use_kernel=False)
+
+    rpb = st.nwk.layout.pad_rows // 4
+    w = np.concatenate([corp.w, np.zeros(st.w.shape[0] - n, np.int32)])
+    valid = np.arange(st.w.shape[0]) < n
+    idx, bval = lda.block_token_index(w, valid, rpb, st.nwk.layout)
+    out = {}
+    for kind, fn, args in (
+            ("snapshot", async_exec._jit_as(
+                "snapshot_sweep", lambda s, k: async_exec.snapshot_sweep(
+                    s, k, cfg, route=route)), (state, key)),
+            ("pipelined", async_exec._jit_as(
+                "pipelined_sweep", lambda s, k, i, b:
+                async_exec.pipelined_sweep(s, k, cfg, i, b, rpb,
+                                           route=route)),
+             (state, key, spec(idx.shape, jnp.int32),
+              spec(bval.shape, jnp.bool_)))):
+        out[kind] = (fn, args, fn.lower(*args).compile().as_text())
+    return out
+
+
+@pytest.mark.parametrize("metric", ["mh_sample_roofline.train",
+                                    "alias_build_roofline.train"])
+def test_kernel_regexes_of_the_benchmark_find_the_named_kernels(sweeps,
+                                                                 metric):
+    path = os.path.join(REPO, "perfbench", "metrics", metric + ".py")
+    ns = {}
+    exec(open(path).read(), ns)
+    hit = [ln for ln in _custom_calls(sweeps["snapshot"][2])
+           if re.search(ns["KERNEL"], ln)]
+    assert len(hit) == 1, _custom_calls(sweeps["snapshot"][2])
+    assert hit[0].startswith("%" + metric.split("_roofline")[0])
+
+
+# instructions that compute nothing themselves: values passed around,
+# control flow, and the start/done markers of the asynchronous copies and
+# slices that XLA's memory-space assignment adds
+FREE = ("parameter", "constant", "get-tuple-element", "tuple", "bitcast",
+        "while", "conditional", "call", "copy-start", "copy-done",
+        "slice-start", "slice-done")
+
+
+@pytest.mark.parametrize("kind", ["snapshot", "pipelined"])
+def test_scope_table_finds_every_phase_of_the_sweep(sweeps, kind,
+                                                    monkeypatch):
+    from repro.obs import scopes
+    fn, args, text = sweeps[kind]
+    monkeypatch.setattr(scopes, "_PROGRAMS", [])
+    scopes.register(fn.__name__, fn, args)
+    table = scopes.scope_table()
+    assert set(scopes.PHASES) <= set(table.values())
+    assert scopes.AMBIGUOUS not in table.values()
+    # every operation of the entry computation and the loop body, the
+    # scan's own counter and the pass-through output copies included
+    entry = re.search(r"^ENTRY %?([\w.\-]+)", text, re.M).group(1)
+    bodies = set(re.findall(r"body=%?([\w.\-]+)", text)) | {entry}
+    ops = [i for i in scopes.instructions(text)
+           if i.computation in bodies and i.opcode not in FREE]
+    unscoped = [i.name for i in ops if i.phase is None]
+    assert len(ops) > 50
+    assert len(unscoped) <= 0.05 * len(ops), unscoped
+    assert "jit_" + fn.__name__ in text

@@ -288,7 +288,8 @@ class _MemoryPlane:
             self.log_fn(
                 f"[lda] snapshot executor: {info['n_blocks']} token "
                 f"blocks, group {info['group']} (staleness "
-                f"{info['staleness']}), route {info['route']}")
+                f"{info['staleness']}), route {info['route']}, "
+                f"nwk_carry {info['nwk_carry']}")
         self.num_tokens = int(jnp.sum(state.valid))
         self.t0 = time.time()
 
@@ -981,7 +982,9 @@ class _SpmdPlane:
         self.info = {"mode": "spmd", "mesh_data": data, "mesh_model": model,
                      "workers": workers,
                      "staleness": self.exec_cfg.staleness,
-                     "route": repr(route)}
+                     "route": repr(route),
+                     "nwk_carry": async_exec.nwk_carry_layout(
+                         route, cfg.V, cfg.K, cfg.use_kernels)}
         self.t0 = time.time()
 
     def schedule(self):
@@ -1137,7 +1140,9 @@ class _StreamSpmdPlane:
                      "tokens_per_shard": meta.tokens_per_shard,
                      "num_tokens": meta.num_tokens,
                      "staleness": self.exec_cfg.staleness,
-                     "route": repr(route)}
+                     "route": repr(route),
+                     "nwk_carry": async_exec.nwk_carry_layout(
+                         route, cfg.V, cfg.K, cfg.use_kernels)}
         self.t0 = time.time()
 
     def schedule(self):

@@ -295,6 +295,29 @@ def pipelined_sweep(state: "lda.SamplerState", key: jax.Array,
 # Full-snapshot executor (generalises lightlda.sweep; paper Alg. 1).
 # ---------------------------------------------------------------------------
 
+def nwk_carry_layout(route: ps.PushRoute, num_rows: int, num_topics: int,
+                     use_kernels: bool) -> str:
+    """How ``snapshot_sweep`` carries the n_wk aggregate through its
+    scan: ``"flat"`` (``[V * K]``) or ``"rows"`` (``[V, K]``).
+
+    XLA lowers an element scatter into a ``[V, K]`` array as a 1-D
+    scatter into a flat copy, so a cold tail scattered into a ``[V, K]``
+    carry relays the whole table out and back in every group.  A flat
+    carry takes the scatter as it is, and the hybrid's ``[H, K]`` hot
+    prefix adds in place to its leading ``H * K`` cells.  Plans with no
+    XLA scatter keep rows: a dense-only plan's ``[V, K]`` delta, or the
+    ``delta_apply_coo`` kernel's, would be relaid out instead; and a
+    flat int32 index cannot address ``2**31`` cells or more.
+    """
+    if num_rows * num_topics >= 2 ** 31 or route.coo_kernel(use_kernels):
+        return "rows"
+    if isinstance(route, ps.CooRoute) or (
+            isinstance(route, ps.HybridRoute)
+            and route.clamped(num_rows) < num_rows):
+        return "flat"
+    return "rows"
+
+
 def snapshot_sweep(state: "lda.SamplerState", key: jax.Array,
                    cfg: "lda.LDAConfig",
                    axis_name=None, model_axis=None,
@@ -316,6 +339,9 @@ def snapshot_sweep(state: "lda.SamplerState", key: jax.Array,
     axes; in-process both are the identity.  The legacy
     ``axis_name``/``model_axis`` kwargs override the handle's backend.
     ``staleness=0`` reproduces the per-block schedule exactly.
+
+    The n_wk aggregate rides the scan in ``nwk_carry_layout``'s layout
+    and is reshaped to ``[V, K]`` once, after the scan.
     """
     n = state.w.shape[0]
     nblocks = n // cfg.block_tokens
@@ -325,6 +351,7 @@ def snapshot_sweep(state: "lda.SamplerState", key: jax.Array,
     gtok = group * cfg.block_tokens
     if route is None:
         route = ps.route_for(hot_words, cfg.V)
+    flat = nwk_carry_layout(route, cfg.V, cfg.K, cfg.use_kernels) == "flat"
 
     # --- backend: the handle's client, unless legacy kwargs override ---
     handle = state.nwk
@@ -407,7 +434,9 @@ def snapshot_sweep(state: "lda.SamplerState", key: jax.Array,
             if plan.dense is not None:
                 d = backend.reduce(plan.dense)
                 h = d.shape[0]
-                if h < cfg.V:
+                if flat:
+                    nwk_dense = nwk_dense.at[:h * cfg.K].add(d.reshape(-1))
+                elif h < cfg.V:
                     nwk_dense = nwk_dense.at[:h, :].add(d)
                 else:
                     nwk_dense = nwk_dense + d
@@ -421,7 +450,11 @@ def snapshot_sweep(state: "lda.SamplerState", key: jax.Array,
                         interpret=cfg.kernel_interpret)
                 else:
                     safe = jnp.clip(c_rows, 0, cfg.V - 1)
-                    nwk_dense = nwk_dense.at[safe, c_cols].add(c_vals)
+                    if flat:
+                        nwk_dense = nwk_dense.at[safe * cfg.K + c_cols].add(
+                            c_vals)
+                    else:
+                        nwk_dense = nwk_dense.at[safe, c_cols].add(c_vals)
             nk = nk + backend.reduce(d_nk)
 
         # n_dk stays local: docs are owned by one worker (paper sec. 3),
@@ -434,12 +467,15 @@ def snapshot_sweep(state: "lda.SamplerState", key: jax.Array,
 
     with jax.named_scope("mh.chain"):
         keys = jax.random.split(key, n_groups)
-    carry = (state.z, state.ndk, snapshot, nk_snap)
+    with jax.named_scope("ps.pull"):
+        nwk0 = snapshot.reshape(-1) if flat else snapshot
+    carry = (state.z, state.ndk, nwk0, nk_snap)
     (z, ndk, nwk_dense, nk), _ = jax.lax.scan(
         group_body, carry, (groups, keys))
 
     # --- write back to the server layout (SPMD keeps only own rows) ---
     with jax.named_scope("ps.push"):
+        nwk_dense = nwk_dense.reshape(snapshot.shape)
         new_nwk = handle.client.matrix_from_dense(
             nwk_dense, route=handle.route).localize()
     return lda.SamplerState(state.w, state.d, z, state.valid,
@@ -574,7 +610,9 @@ def make_stream_executor(cfg: "lda.LDAConfig", exec_cfg: ExecConfig,
     info = {"mode": "snapshot", "n_blocks": None, "rows_per_block": None,
             "staleness": exec_cfg.staleness,
             "staleness_requested": exec_cfg.staleness,
-            "hot_words": exec_cfg.hot_words, "route": repr(route)}
+            "hot_words": exec_cfg.hot_words, "route": repr(route),
+            "nwk_carry": nwk_carry_layout(route, cfg.V, cfg.K,
+                                          cfg.use_kernels)}
     return _obs_step(jit_step, exec_cfg, info), None, info
 
 
@@ -626,7 +664,9 @@ def make_executor(state: "lda.SamplerState", cfg: "lda.LDAConfig",
                 "rows_per_block": None, "staleness": s, "group": s + 1,
                 "token_cap": cfg.block_tokens,
                 "staleness_requested": exec_cfg.staleness,
-                "hot_words": exec_cfg.hot_words, "route": repr(route)}
+                "hot_words": exec_cfg.hot_words, "route": repr(route),
+                "nwk_carry": nwk_carry_layout(route, cfg.V, cfg.K,
+                                              cfg.use_kernels)}
     if report is not None:
         info["autotune"] = report
     return _obs_step(step, exec_cfg, info), info

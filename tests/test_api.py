@@ -179,6 +179,18 @@ class TestMemoryEquivalence:
         assert int(out.nk.value.sum()) == int(state.nk.value.sum())
         assert info["mode"] in ("snapshot", "blocked")
 
+    @pytest.mark.parametrize("route,layout", [
+        (api.DenseRoute(), "rows"), (api.HybridRoute(hot_words=32), "flat"),
+    ])
+    def test_session_reports_nwk_carry(self, tiny_corpus, route, layout):
+        lines = []
+        sess = api.Session(_mem_job(tiny_corpus, route=route),
+                           log_fn=lines.append)
+        _, _, info = sess.make_step()
+        assert info["nwk_carry"] == layout
+        assert any(ln.startswith("[lda] snapshot executor")
+                   and ln.endswith(f"nwk_carry {layout}") for ln in lines)
+
 
 class TestStreamEquivalence:
     def test_bitwise_vs_fit_lda_stream(self, tiny_corpus, tmp_path):

@@ -320,6 +320,79 @@ class TestRouteInvarianceRandom:
         run()
 
 
+class TestSnapshotSweepCarry:
+    """``snapshot_sweep`` carries its n_wk aggregate flat when the plan
+    has an XLA-scattered cold tail, as ``[V, K]`` rows otherwise
+    (``async_exec.nwk_carry_layout``).  The layout is a relayout, never a
+    change of values."""
+
+    V, K = 300, 100          # K is a multiple of no tile
+
+    @pytest.mark.parametrize("staleness", [0, 2])
+    @pytest.mark.parametrize("route,use_kernels,layout", [
+        (ps.DenseRoute(), False, "rows"),
+        (ps.CooRoute(), False, "flat"),
+        (ps.HybridRoute(hot_words=1), False, "flat"),
+        (ps.HybridRoute(hot_words=V // 2), False, "flat"),
+        (ps.HybridRoute(hot_words=V // 2, use_kernel=False), True, "flat"),
+    ], ids=["dense", "coo", "hybrid-1", "hybrid-half", "hybrid-kernels"])
+    def test_matches_sweep_oracle(self, lda_state, route, use_kernels,
+                                  layout, staleness):
+        from repro.core import lightlda as lda
+        from repro.train import async_exec
+
+        corp, cfg, state = lda_state(seed=9, vocab=self.V, k=self.K,
+                                     use_kernels=use_kernels)
+        assert async_exec.nwk_carry_layout(route, cfg.V, cfg.K,
+                                           use_kernels) == layout
+        key = jax.random.PRNGKey(23)
+        want = jax.jit(lambda s, k: lda.sweep(
+            s, k, cfg, staleness=staleness))(state, key)
+        got = jax.jit(lambda s, k: async_exec.snapshot_sweep(
+            s, k, cfg, staleness=staleness, route=route))(state, key)
+        np.testing.assert_array_equal(np.asarray(got.z), np.asarray(want.z))
+        np.testing.assert_array_equal(np.asarray(got.nwk.value),
+                                      np.asarray(want.nwk.value))
+        np.testing.assert_array_equal(np.asarray(got.nk.value),
+                                      np.asarray(want.nk.value))
+        np.testing.assert_array_equal(np.asarray(got.ndk),
+                                      np.asarray(want.ndk))
+        # and the counts are the histograms of the new assignments
+        nwk, nk, ndk = lda.rebuild_counts(got.w, got.d, got.z, got.valid,
+                                          got.ndk.shape[0], cfg)
+        np.testing.assert_array_equal(np.asarray(nwk.value),
+                                      np.asarray(got.nwk.value))
+        assert int(got.nk.value.sum()) == corp.num_tokens
+
+    @pytest.mark.parametrize("route,v,k,use_kernels,layout", [
+        (ps.CooRoute(), 300, 100, False, "flat"),
+        (ps.HybridRoute(hot_words=0), 300, 100, False, "flat"),
+        (ps.HybridRoute(hot_words=1), 300, 100, False, "flat"),
+        (ps.HybridRoute(hot_words=2000, use_kernel=False), 102_660, 1024,
+         True, "flat"),
+        # the paper's 10x topics still fits a flat int32 index
+        (ps.HybridRoute(hot_words=2000, use_kernel=False), 102_660, 10_240,
+         True, "flat"),
+        (ps.HybridRoute(hot_words=2000, use_kernel=False), 2 ** 21 - 1,
+         1024, False, "flat"),
+        (ps.HybridRoute(hot_words=2000, use_kernel=False), 2 ** 21, 1024,
+         False, "rows"),
+        (ps.CooRoute(), 2 ** 16, 2 ** 15, False, "rows"),
+        (ps.DenseRoute(), 300, 100, False, "rows"),
+        (ps.DenseRoute(), 300, 100, True, "rows"),
+        (ps.HybridRoute(hot_words=300), 300, 100, False, "rows"),
+        (ps.HybridRoute(hot_words=10_000), 300, 100, False, "rows"),
+        (ps.CooRoute(), 300, 100, True, "rows"),
+        (ps.CooRoute(use_kernel=True), 300, 100, False, "rows"),
+        (ps.HybridRoute(hot_words=1), 300, 100, True, "rows"),
+    ])
+    def test_layout_follows_the_plan(self, route, v, k, use_kernels,
+                                     layout):
+        from repro.train import async_exec
+        assert async_exec.nwk_carry_layout(route, v, k,
+                                           use_kernels) == layout
+
+
 class TestPushCooPaddingInvariant:
     """Regression: raw ``DistributedMatrix.push_sparse`` trusts its row
     ids; the client layer must mask padded logical ids >= num_rows, which

@@ -239,3 +239,55 @@ def test_scope_table_finds_every_phase_of_the_sweep(sweeps, kind,
     assert len(ops) > 50
     assert len(unscoped) <= 0.05 * len(ops), unscoped
     assert "jit_" + fn.__name__ in text
+
+
+# ---------------------------------------------------------------------------
+# The n_wk aggregate stays out of relayouts inside the sweep's loop
+# ---------------------------------------------------------------------------
+
+K_NYT = 1024
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+) .*\{$")
+
+
+def _loop_relayouts(text, v, k):
+    """Reshapes and copies in a loop body whose result is a whole
+    ``[v, k]`` int32 table, in the tiled or the flat layout."""
+    bodies = set(re.findall(r"body=%?([\w.\-]+)", text))
+    whole = re.compile(rf" = s32\[(?:{v},{k}|{v * k})\]\S* (?:reshape|copy)\(")
+    computation, found = None, []
+    for line in text.splitlines():
+        c = _COMPUTATION.match(line)
+        if c is not None:
+            computation = c.group(1)
+        elif computation in bodies and whole.search(line):
+            found.append(line.strip()[:120])
+    return found
+
+
+@pytest.mark.parametrize("route", ["hybrid", "dense"])
+def test_sweep_loop_has_no_nwk_relayout(one_chip, route):
+    """``snapshot_sweep`` at NYTimes widths, Pallas path: the training
+    cells' hybrid push (its cold tail scattered by XLA) and the dense
+    push.  Neither relays the whole n_wk table out in the loop."""
+    from repro import ps
+    from repro.core import lightlda as lda
+    from repro.train import async_exec
+
+    cfg = lda.LDAConfig(num_topics=K_NYT, vocab_size=V_NYT,
+                        block_tokens=8192, use_kernels=True,
+                        kernel_interpret=False)
+    n, num_docs = 20_000, 300
+    st = jax.eval_shape(
+        lambda w, d: lda.init_state(jax.random.PRNGKey(0), w, d, num_docs,
+                                    cfg),
+        jax.ShapeDtypeStruct((n,), jnp.int32),
+        jax.ShapeDtypeStruct((n,), jnp.int32))
+    spec = _spec(one_chip)
+    state = jax.tree.map(lambda x: spec(x.shape, x.dtype), st)
+    rt = {"hybrid": ps.HybridRoute(hot_words=2000, use_kernel=False),
+          "dense": ps.DenseRoute()}[route]
+    text = jax.jit(lambda s, k: async_exec.snapshot_sweep(
+        s, k, cfg, route=rt)).lower(state, spec((2,), jnp.uint32)) \
+        .compile().as_text()
+    assert "body=" in text
+    assert _loop_relayouts(text, V_NYT, K_NYT) == []

@@ -78,22 +78,34 @@ def eval_callback(heldout):
     """The repo's ``EvalCallback`` after every sweep -- training
     perplexity plus held-out fold-in perplexity.  It waits for each sweep
     to finish before its own clock starts, so ``sweep_s`` holds each
-    sweep's seconds (dispatch to done) and ``seconds`` evaluation's."""
+    sweep's seconds (dispatch to done), ``sweep_compiles`` the programs
+    JAX compiled (or read from its cache) in each, and ``seconds``
+    evaluation's."""
+    import jax
+
     from repro import api
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, _, **kw: compiles.append(kw.get("fun_name"))
+        if event == "/jax/core/compile/backend_compile_duration" else None)
 
     class TimedEval(api.EvalCallback):
         seconds = 0.0
 
         def on_fit_start(self, info):
             self.sweep_s = []
+            self.sweep_compiles = []
             self.mark = time.perf_counter()
+            self.seen = len(compiles)
 
         def on_sweep_end(self, view):
             view.sync()
             t0 = time.perf_counter()
             self.sweep_s.append(t0 - self.mark)
+            self.sweep_compiles.append(len(compiles) - self.seen)
             super().on_sweep_end(view)
             self.mark = time.perf_counter()
+            self.seen = len(compiles)
             self.seconds += self.mark - t0
 
     return TimedEval(every=1, include_last=False, heldout=heldout,
@@ -137,7 +149,8 @@ def perplexities(rep: Report, phase: str, history):
 
 def train(rep: Report, phase: str, job, heldout):
     """``APSLDA(job).fit()`` with evaluation after every sweep; then the
-    conservation checks.  Returns (model, result, held-out perplexities)."""
+    conservation checks.  Returns (model, result, held-out perplexities,
+    programs compiled per sweep)."""
     from repro import api
     est = api.APSLDA(job)
     ev = eval_callback(heldout)
@@ -152,10 +165,11 @@ def train(rep: Report, phase: str, job, heldout):
     print(f"[{phase}] fit seconds {fit_s:.3f}: set-up {setup_s:.3f}, "
           f"evaluation {ev.seconds:.3f}, sweeps {sweeps} (the first "
           f"includes compilation; {ntok} tokens per sweep, "
-          f"{ntok / ev.sweep_s[-1]:.1f} tokens/s in the last)", flush=True)
+          f"{ntok / ev.sweep_s[-1]:.1f} tokens/s in the last); programs "
+          f"compiled per sweep {ev.sweep_compiles}", flush=True)
     conservation(rep, phase, res.state, res.nwk, res.nk, model.cfg)
     held_p = perplexities(rep, phase, ev.history)
-    return model, res, held_p
+    return model, res, held_p, ev.sweep_compiles
 
 
 def make_corpus(rep: Report, args):
@@ -206,7 +220,7 @@ def phase_c(rep, args, train_c, held, use_kernels: bool = False):
     print(f"[c] {'Pallas' if use_kernels else 'jnp'} path: snapshot "
           f"executor, block_tokens {job.block_tokens}, route {job.route}",
           flush=True)
-    model, res, held_p = train(rep, "c", job, held)
+    model, res, held_p, _ = train(rep, "c", job, held)
     rep.phase("c", time.perf_counter() - t0)
     return model, res.state.z, held_p
 
@@ -236,7 +250,7 @@ def phase_d(rep, args, train_c, held, ref):
               hlo.count("tpu_custom_call") >= 3,
               f"{hlo.count('tpu_custom_call')} occurrences")
     del session, state, step, hlo
-    model, res, values = train(rep, "d", job, held)
+    model, res, values, _ = train(rep, "d", job, held)
 
     model_c, z_c, values_c = ref
     valid = res.state.valid
@@ -320,7 +334,10 @@ def phase_e(rep, model_c, model_d, held):
 
 def four_chips(rep, args, train_c, held, ref_values):
     """The SPMD plane on a (data=2, model=2) mesh: servers on the model
-    axis hold cyclic row shards of nwk (paper section 2.2)."""
+    axis hold cyclic row shards of nwk (paper section 2.2).  ``fit()``
+    runs the one compiled sweep that ``Session.make_step()`` hands out,
+    its state placed with the sweep's shardings: one compile, in the
+    first sweep."""
     import jax
 
     from repro import api
@@ -329,7 +346,10 @@ def four_chips(rep, args, train_c, held, ref_values):
                    use_kernels=True)
     print(f"[spmd] mesh data=2 x model=2, Pallas path, snapshot executor, "
           f"block_tokens {job.block_tokens}, route {job.route}", flush=True)
-    model, res, values = train(rep, "spmd", job, held)
+    model, res, values, compiles = train(rep, "spmd", job, held)
+    rep.check("spmd", "one compile, in the first sweep",
+              compiles[0] >= 1 and not any(compiles[1:]),
+              f"programs compiled per sweep {compiles}")
     layout = res.nwk.layout
     shards = sorted((s.device.id, tuple(s.data.shape))
                     for s in res.nwk.value.addressable_shards)

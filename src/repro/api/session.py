@@ -125,6 +125,38 @@ def init_stream(reader, cfg, seed: int = 0, client=None):
 # SPMD wiring (moved here from launch/lda.py; the launcher re-exports).
 # ---------------------------------------------------------------------------
 
+class SpmdState(NamedTuple):
+    """The SPMD plane's training state, every array placed with the
+    sweep's own sharding (``spmd_specs``).
+
+    The per-worker arrays carry a leading worker dim whose row ``j`` lives
+    on the mesh's ``j``-th device in ``(data, model)`` order: worker
+    ``j``'s padded tokens (``w``, ``d``, ``z``, ``valid``), its documents
+    (``doc_start``, ``doc_len``) and their counts (``ndk`` [workers, dmax,
+    K]).  ``nwk`` is the physical (cyclic-ordered) ``[pad_rows, K]`` count
+    table, rows sharded over the model (server) axis; ``nk`` [K] is
+    replicated.  The field order is the sweep's argument order.
+    """
+
+    w: jax.Array
+    d: jax.Array
+    z: jax.Array
+    valid: jax.Array
+    doc_start: jax.Array
+    doc_len: jax.Array
+    ndk: jax.Array
+    nwk: jax.Array
+    nk: jax.Array
+
+
+def spmd_specs() -> SpmdState:
+    """The ``PartitionSpec`` of each ``SpmdState`` field."""
+    from jax.sharding import PartitionSpec as P
+    wspec = P(("data", "model"), None)
+    return SpmdState(wspec, wspec, wspec, wspec, wspec, wspec,
+                     P(("data", "model"), None, None), P("model", None), P())
+
+
 def make_spmd_sweep(mesh, cfg: "lda.LDAConfig", staleness: int = 0,
                     hot_words=None, route: Optional["ps.PushRoute"] = None):
     """shard_map'd sweep: tokens split over (data, model); n_wk rows cyclic
@@ -135,7 +167,8 @@ def make_spmd_sweep(mesh, cfg: "lda.LDAConfig", staleness: int = 0,
     thread through: with ``staleness`` s, each worker merges (and psums)
     deltas once per group of s+1 token blocks -- fewer, larger
     collectives -- and ``route`` (or the legacy ``hot_words``) selects the
-    push policy (dense / coordinate / hybrid)."""
+    push policy (dense / coordinate / hybrid).  Arguments are the
+    ``SpmdState`` fields, then the ``[workers, 2]`` per-worker keys."""
     from jax.sharding import PartitionSpec as P
 
     client = ps.client_for(cfg, axis_name=("data", "model"),
@@ -151,54 +184,123 @@ def make_spmd_sweep(mesh, cfg: "lda.LDAConfig", staleness: int = 0,
                         route=route)
         return (out.z[None], out.ndk[None], out.nwk.value, out.nk.value)
 
-    wspec = P(("data", "model"), None)
+    specs = spmd_specs()
     return jax.shard_map(
         local, mesh=mesh,
-        in_specs=(wspec, wspec, wspec, wspec, wspec, wspec,
-                  P(("data", "model"), None, None), P("model", None),
-                  P(), wspec),
-        out_specs=(wspec, P(("data", "model"), None, None),
-                   P("model", None), P()),
+        in_specs=tuple(specs) + (P(("data", "model"), None),),
+        out_specs=(specs.z, specs.ndk, specs.nwk, specs.nk),
         check_vma=False)
 
 
-def init_distributed_state(corp, cfg: "lda.LDAConfig", workers: int,
-                           key: jax.Array):
-    """Shard the corpus over ``workers`` and build the global count tables
-    (the same rebuild the checkpoint recovery uses, paper section 3.5).
+def make_spmd_step(mesh, cfg: "lda.LDAConfig", workers: int,
+                   staleness: int = 0,
+                   route: Optional["ps.PushRoute"] = None):
+    """The SPMD plane's jitted sweep, ``step(state, key) -> state`` over
+    an ``SpmdState``: worker ``j`` samples with ``split(key, workers)[j]``.
+    Compiled as ``jit_spmd_sweep``; inputs placed with ``spmd_specs``
+    come back with the same shardings, so consecutive sweeps share one
+    executable."""
+    sweep = make_spmd_sweep(mesh, cfg, staleness=staleness, route=route)
 
-    Returns ``(w, d, valid, doc_start, doc_len, z, ndk, nwk, nk)`` with a
-    leading worker dim on the per-worker arrays; ``nwk`` is cyclic over
-    ``cfg.num_shards``.  Shared by the SPMD planes and the SPMD tests.
+    def spmd_sweep(state: SpmdState, key: jax.Array) -> SpmdState:
+        z, ndk, nwk, nk = sweep(*state, jax.random.split(key, workers))
+        return state._replace(z=z, ndk=ndk, nwk=nwk, nk=nk)
+
+    return async_exec._jit_as("spmd_sweep", spmd_sweep)
+
+
+def spmd_exchange_bytes(cfg: "lda.LDAConfig", route: "ps.PushRoute",
+                        data: int, model: int, tokens_per_worker: int,
+                        staleness: int = 0) -> dict:
+    """Bytes each device puts into the sweep's collectives per sweep, by
+    collective, from the static shapes: the snapshot all-gather of its
+    server shard, and per group the hot dense delta's psum, the cold COO
+    buffers' all-gather (one per worker axis, the second carrying what the
+    first gathered) and the n_k delta's psum."""
+    n_blocks = tokens_per_worker // cfg.block_tokens
+    group = async_exec.effective_staleness(n_blocks, staleness) + 1
+    n_groups = n_blocks // group
+    gtok = group * cfg.block_tokens
+    tok = jax.ShapeDtypeStruct((gtok,), jnp.int32)
+    plan = jax.eval_shape(
+        lambda w, z0, z1: route.plan(
+            ps.Reassign(rows=w, words=w, z_old=z0, z_new=z1,
+                        changed=z0 != z1),
+            cfg.V, cfg.K, use_kernels=cfg.use_kernels, prefix_rows=True,
+            interpret=cfg.kernel_interpret), tok, tok, tok)
+    nbytes = lambda a: int(np.prod(a.shape)) * a.dtype.itemsize
+    rows_per_shard = -(-cfg.V // model)
+    coo = sum(nbytes(a) for a in plan.coo) if plan.coo is not None else 0
+    return {"pull_all_gather": rows_per_shard * cfg.K * 4,
+            "hot_psum": n_groups * (nbytes(plan.dense)
+                                    if plan.dense is not None else 0),
+            "cold_all_gather": n_groups * coo * (1 + data),
+            "nk_psum": n_groups * cfg.K * 4}
+
+
+def init_distributed_state(corp, cfg: "lda.LDAConfig", workers: int,
+                           key: jax.Array, mesh=None):
+    """Shard the corpus over ``workers`` and build the count tables on
+    the devices that own them (the checkpoint recovery's rebuild, paper
+    section 3.5).
+
+    Returns an ``SpmdState``, each array placed with its ``spmd_specs``
+    sharding on ``mesh`` (default: ``(workers / cfg.num_shards,
+    cfg.num_shards)`` over every device): the per-worker arrays go
+    straight from the host to their worker's device; each worker
+    histograms its own ``ndk``, each server its own cyclic rows of
+    ``nwk`` from every worker's tokens; ``nk`` is psum'd.  No whole table
+    is built on one device.  Shared by the SPMD planes and the SPMD tests.
     """
+    from jax.sharding import NamedSharding
+
     from repro.data import corpus as corpus_mod
+
+    model = cfg.num_shards
+    if mesh is None:
+        mesh = make_mesh((workers // model, model), ("data", "model"))
+    specs = spmd_specs()
+    put = lambda x, spec: jax.device_put(x, NamedSharding(mesh, spec))
 
     shards = corpus_mod.shard_tokens(corp, workers, cfg.block_tokens)
     npad = max(s[0].shape[0] for s in shards)
     dmax = max(s[3].shape[0] for s in shards)
 
-    def stack(i, pad_to, fill=0):
-        return np.stack([
-            np.pad(s[i], (0, pad_to - len(s[i])), constant_values=fill)
-            for s in shards])
+    def stack(i, pad_to):
+        return np.stack([np.pad(s[i], (0, pad_to - len(s[i])))
+                         for s in shards])
 
-    w = jnp.asarray(stack(0, npad))
-    d = jnp.asarray(stack(1, npad))
-    valid = jnp.asarray(stack(2, npad))
-    doc_start = jnp.asarray(stack(3, dmax))
-    doc_len = jnp.asarray(stack(4, dmax))
+    w, d, valid = (put(stack(i, npad), specs.w) for i in range(3))
+    doc_start, doc_len = (put(stack(i, dmax), specs.doc_start)
+                          for i in (3, 4))
+    z = jax.jit(lambda k: jax.random.randint(k, (workers, npad), 0, cfg.K,
+                                             dtype=jnp.int32),
+                out_shardings=NamedSharding(mesh, specs.z))(key)
 
-    z = jax.random.randint(key, w.shape, 0, cfg.K, dtype=jnp.int32)
-    # counts from the global view (same rebuild the checkpoint recovery uses)
-    one = valid.reshape(-1).astype(jnp.int32)
-    nwk_dense = jnp.zeros((cfg.V, cfg.K), jnp.int32).at[
-        w.reshape(-1), z.reshape(-1)].add(one)
-    nk = jnp.zeros((cfg.K,), jnp.int32).at[z.reshape(-1)].add(one)
-    ndk = jnp.zeros((workers, dmax, cfg.K), jnp.int32)
-    idx = jnp.arange(workers)[:, None].repeat(npad, 1)
-    ndk = ndk.at[idx.reshape(-1), d.reshape(-1), z.reshape(-1)].add(one)
-    nwk = ps.client_for(cfg).matrix_from_dense(nwk_dense)
-    return w, d, valid, doc_start, doc_len, z, ndk, nwk, nk
+    rows = -(-cfg.V // model)
+    workers_ax = ("data", "model")
+
+    def local(w, d, z, valid):
+        one = valid[0].astype(jnp.int32)
+        ndk = jnp.zeros((1, dmax, cfg.K), jnp.int32).at[0, d[0], z[0]].add(
+            one)
+        nk = jax.lax.psum(jnp.zeros((cfg.K,), jnp.int32).at[z[0]].add(one),
+                          workers_ax)
+        # this server's cyclic rows (word % model == server), counted
+        # from every worker's tokens; other rows fall out of range
+        wa, za, va = (jax.lax.all_gather(x[0], workers_ax, tiled=True)
+                      for x in (w, z, valid))
+        mine = va & (wa % model == jax.lax.axis_index("model"))
+        row = jnp.where(mine, wa // model, rows)
+        nwk = jnp.zeros((rows, cfg.K), jnp.int32).at[row, za].add(
+            1, mode="drop")
+        return ndk, nwk, nk
+
+    ndk, nwk, nk = jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=(specs.w,) * 4,
+        out_specs=(specs.ndk, specs.nwk, specs.nk), check_vma=False))(
+        w, d, z, valid)
+    return SpmdState(w, d, z, valid, doc_start, doc_len, ndk, nwk, nk)
 
 
 # ---------------------------------------------------------------------------
@@ -939,8 +1041,9 @@ class _SpmdPlane:
     Workers (all mesh shards) sample their document partitions; servers
     (the model axis) hold cyclic rows of ``n_wk``.  RNG matches the old
     launcher loop bitwise: ``key = PRNGKey(seed)`` seeds the shared z
-    init, then ``key, sub = split(key)`` + ``split(sub, workers)`` per
-    sweep.
+    init, then ``key, sub = split(key)`` per sweep, and the step splits
+    ``sub`` over the workers (``make_spmd_step``).  ``step_fn`` is the
+    one compiled sweep that ``Session.make_step()`` hands out.
     """
 
     kind = "spmd"
@@ -968,23 +1071,32 @@ class _SpmdPlane:
         self.cfg = cfg
         self.log_fn(f"[lda] mesh data={data} x model={model} "
                     f"({workers} workers, {model} servers)")
-        key = jax.random.PRNGKey(self.seed)
-        (self.w, self.d, self.valid, self.doc_start, self.doc_len, self.z,
-         self.ndk, nwk, nk) = init_distributed_state(self.corp, cfg,
-                                                     workers, key)
-        self.key = key
+        self.key = jax.random.PRNGKey(self.seed)
+        self.state = st = init_distributed_state(self.corp, cfg, workers,
+                                                 self.key, mesh=mesh)
+        staleness = self.exec_cfg.staleness
         route = self.exec_cfg.resolve_route(cfg.V)
-        self.sweep_fn = jax.jit(make_spmd_sweep(
-            mesh, cfg, staleness=self.exec_cfg.staleness, route=route))
-        self.nwk_val, self.nk_val = nwk.value, nk
-        self.dmax = self.doc_start.shape[1]
-        self.num_tokens = int(jnp.sum(self.valid))
+        self.dmax = st.doc_start.shape[1]
+        self.num_tokens = int(jnp.sum(st.valid))
+        n_blocks = st.w.shape[1] // cfg.block_tokens
+        group = async_exec.effective_staleness(n_blocks, staleness) + 1
         self.info = {"mode": "spmd", "mesh_data": data, "mesh_model": model,
-                     "workers": workers,
-                     "staleness": self.exec_cfg.staleness,
+                     "workers": workers, "n_blocks": n_blocks,
+                     "staleness": group - 1, "group": group,
                      "route": repr(route),
                      "nwk_carry": async_exec.nwk_carry_layout(
-                         route, cfg.V, cfg.K, cfg.use_kernels)}
+                         route, cfg.V, cfg.K, cfg.use_kernels),
+                     "exchange_bytes_per_sweep": spmd_exchange_bytes(
+                         cfg, route, data, model, st.w.shape[1],
+                         staleness)}
+        self.step_fn = async_exec._obs_step(
+            make_spmd_step(mesh, cfg, workers, staleness, route),
+            self.exec_cfg, self.info)
+        self.log_fn(f"[lda] spmd executor: {n_blocks} token blocks per "
+                    f"worker, group {group}, route {self.info['route']}, "
+                    f"nwk_carry {self.info['nwk_carry']}, exchange bytes "
+                    f"per device per sweep "
+                    f"{self.info['exchange_bytes_per_sweep']}")
         self.t0 = time.time()
 
     def schedule(self):
@@ -992,28 +1104,26 @@ class _SpmdPlane:
 
     def step(self, i: int):
         self.key, sub = jax.random.split(self.key)
-        keys = jax.random.split(sub, self.workers)
-        self.z, self.ndk, self.nwk_val, self.nk_val = self.sweep_fn(
-            self.w, self.d, self.z, self.valid, self.doc_start,
-            self.doc_len, self.ndk, self.nwk_val, self.nk_val, keys)
+        self.state = self.step_fn(self.state, sub)
 
     def _handles(self):
         client = ps.client_for(self.cfg)
-        return (client.wrap_matrix(self.nwk_val, self.cfg.V),
-                client.wrap_vector(self.nk_val))
+        return (client.wrap_matrix(self.state.nwk, self.cfg.V),
+                client.wrap_vector(self.state.nk))
 
     def _global_state(self) -> "lda.SamplerState":
         """Every worker's partition as one flat ``SamplerState``: worker
         ``j``'s doc ids (and token offsets) shift by ``j`` times the
         per-worker capacity, so ``ndk`` rows stay distinct."""
-        k, n = self.cfg.K, self.w.shape[1]
+        st, k = self.state, self.cfg.K
+        n = st.w.shape[1]
         wk = jnp.arange(self.workers)[:, None]
         nwk, nk = self._handles()
         return lda.SamplerState(
-            self.w.reshape(-1), (self.d + wk * self.dmax).reshape(-1),
-            self.z.reshape(-1), self.valid.reshape(-1),
-            (self.doc_start + wk * n).reshape(-1), self.doc_len.reshape(-1),
-            nwk, nk, self.ndk.reshape(self.workers * self.dmax, k))
+            st.w.reshape(-1), (st.d + wk * self.dmax).reshape(-1),
+            st.z.reshape(-1), st.valid.reshape(-1),
+            (st.doc_start + wk * n).reshape(-1), st.doc_len.reshape(-1),
+            nwk, nk, st.ndk.reshape(self.workers * self.dmax, k))
 
     def view(self, i: int) -> SweepView:
         nwk, nk = self._handles()
@@ -1024,12 +1134,12 @@ class _SpmdPlane:
 
     # -- observation hooks ------------------------------------------------
     def sync(self, view):
-        jax.block_until_ready(self.z)
+        jax.block_until_ready(self.state.z)
 
     def perplexity(self, view) -> float:
         cfg, st = self.cfg, self._global_state()
         return float(ppl.training_perplexity(
-            st.w, st.d, st.valid, st.ndk, view.nwk.to_dense(), self.nk_val,
+            st.w, st.d, st.valid, st.ndk, view.nwk.to_dense(), st.nk.value,
             cfg.alpha, cfg.beta))
 
     def history_row(self, view, p: float) -> dict:
@@ -1119,8 +1229,8 @@ class _StreamSpmdPlane:
         nwk, nk = init_stream(self.reader, cfg, self.seed)
         self.nwk_val, self.nk_val = nwk.value, nk.value
         route = self.exec_cfg.resolve_route(cfg.V)
-        self.sweep_fn = jax.jit(make_spmd_sweep(
-            mesh, cfg, staleness=self.exec_cfg.staleness, route=route))
+        self.step_fn = make_spmd_step(mesh, cfg, workers,
+                                      self.exec_cfg.staleness, route)
         self.loader = stream_mod.StreamingLoader(self.reader,
                                                  seed=self.seed,
                                                  prefetch=False,
@@ -1172,9 +1282,9 @@ class _StreamSpmdPlane:
             one.reshape(-1))
         cur0 = group[0][0]
         key = stream_sweep_key(self.seed, cur0.epoch, cur0.pos)
-        keys = jax.random.split(key, self.workers)
-        z2, ndk2, self.nwk_val, self.nk_val = self.sweep_fn(
-            w, d, z, valid, ds, dl, ndk, self.nwk_val, self.nk_val, keys)
+        out = self.step_fn(SpmdState(w, d, z, valid, ds, dl, ndk,
+                                     self.nwk_val, self.nk_val), key)
+        z2, ndk2, self.nwk_val, self.nk_val = out.z, out.ndk, out.nwk, out.nk
         z2_np = np.asarray(z2)
         for j, (_, sid) in enumerate(group):
             reader.write_z(sid, z2_np[j])
@@ -1295,8 +1405,8 @@ class Session:
     ``SessionResult``; the session wires the job's eval cadence and
     checkpoint policy in as callbacks (before the caller's, matching the
     pre-redesign eval-then-checkpoint ordering).  ``make_step()`` exposes
-    the compiled executor of an in-memory in-process job for
-    benchmark-grade timing loops.
+    the compiled executor of an in-memory job (in-process, tiered or
+    SPMD) for benchmark-grade timing loops.
     """
 
     def __init__(self, job: LDAJob, log_fn=print):
@@ -1390,13 +1500,16 @@ class Session:
         return res._replace(history=ev.history if ev is not None else [])
 
     def make_step(self):
-        """Benchmark access for in-memory in-process jobs: returns
-        ``(state, step_fn, info)`` with ``step_fn(state, key) -> state``
-        the compiled executor, so timing loops drive it directly."""
+        """Benchmark access for in-memory jobs: returns ``(state,
+        step_fn, info)`` with ``step_fn(state, key) -> state`` the
+        compiled executor the plane's ``step`` runs, so timing loops
+        drive it directly.  In-process the state is a ``SamplerState``;
+        on the SPMD backend an ``SpmdState`` placed with the sweep's
+        shardings, and ``step_fn`` splits ``key`` over the workers."""
         plane = self._ensure_plane()
-        if plane.kind not in ("memory", "tiered"):
+        if plane.kind not in ("memory", "tiered", "spmd"):
             raise ValueError(
-                "make_step() exposes the in-memory in-process executor "
-                "only; drive other planes through run()")
+                "make_step() exposes the in-memory executors only; drive "
+                "streamed and networked planes through run()")
         plane.setup()
         return plane.state, plane.step_fn, plane.info

@@ -20,6 +20,8 @@ import threading
 from typing import Any, Dict, List, NamedTuple, Optional
 
 PHASES = ("ps.pull", "alias.tables", "mh.chain", "ps.push", "ndk.merge")
+# the scope, nested in a phase, of the collectives between devices
+EXCHANGE = "exchange"
 # the entry of a name that two registered programs place in different
 # phases: the table never guesses which program ran
 AMBIGUOUS = "(ambiguous)"
@@ -35,7 +37,7 @@ _NAME = re.compile(r"%([\w.\-]+)")
 _STRUCTURAL = ("tuple", "while", "conditional", "call")
 
 _LOCK = threading.Lock()
-_PROGRAMS: List[dict] = []   # {"name", "fn", "args", "table"}
+_PROGRAMS: List[dict] = []   # {"name", "fn", "args", "table", "exchange"}
 
 
 class Instruction(NamedTuple):
@@ -43,6 +45,7 @@ class Instruction(NamedTuple):
     name: str
     opcode: str
     phase: Optional[str]
+    exchange: bool = False
 
 
 def phase_of(op_name: str) -> Optional[str]:
@@ -65,6 +68,16 @@ def _operands(rest: str) -> List[str]:
     return _NAME.findall(rest)
 
 
+def in_exchange(op_name: str) -> bool:
+    """Whether an ``op_name`` path has ``EXCHANGE`` as a component."""
+    return EXCHANGE in op_name.split("/")
+
+
+def _all(flags) -> bool:
+    flags = list(flags)
+    return bool(flags) and all(flags)
+
+
 def _one(phases) -> Optional[str]:
     found = {p for p in phases if p is not None}
     return found.pop() if len(found) == 1 else None
@@ -82,12 +95,17 @@ def instructions(hlo_text: str) -> List[Instruction]:
     the one phase its operands share; failing that, the one phase its
     users share.  Otherwise it has none.  A tuple or control-flow
     instruction takes its own ``op_name``'s phase only, since it gathers
-    values of every phase.
+    values of every phase.  Whether an instruction lies under
+    ``EXCHANGE`` follows the same rules, where operands or users must all
+    lie under it.
     """
     rows = []
     computation = ""
     phase: Dict[str, Optional[str]] = {}     # instruction -> phase
     root: Dict[str, Optional[str]] = {}      # computation -> root's phase
+    exch: Dict[str, bool] = {}               # instruction -> under exchange
+    root_exch: Dict[str, bool] = {}
+    named = set()                            # instructions with an op_name
     users: Dict[str, List[str]] = {}
     for line in hlo_text.splitlines():
         m = _INST.match(line)
@@ -102,17 +120,30 @@ def instructions(hlo_text: str) -> List[Instruction]:
             users.setdefault(n, []).append(name)
         op = _OP_NAME.search(rest)
         p = phase_of(op.group(1)) if op else None
+        e = in_exchange(op.group(1)) if op else False
+        if op:
+            named.add(name)
+        elif opcode not in _STRUCTURAL:
+            called = _CALLS.findall(rest)
+            e = (_all(root_exch.get(c, False) for c in called)
+                 or _all(exch.get(n, False) for n in operands))
         if p is None and opcode not in _STRUCTURAL:
             p = (_one(root.get(c) for c in _CALLS.findall(rest))
                  or _one(phase.get(n) for n in operands))
         phase[name] = p
+        exch[name] = e
         if line.lstrip().startswith("ROOT"):
             root[computation] = p
+            root_exch[computation] = e
         rows.append((computation, name, opcode))
     for _, name, opcode in reversed(rows):
-        if phase[name] is None and opcode not in _STRUCTURAL:
+        if opcode in _STRUCTURAL:
+            continue
+        if phase[name] is None:
             phase[name] = _one(phase[u] for u in users.get(name, ()))
-    return [Instruction(c, n, o, phase[n]) for c, n, o in rows]
+        if not exch[name] and name not in named:
+            exch[name] = _all(exch[u] for u in users.get(name, ()))
+    return [Instruction(c, n, o, phase[n], exch[n]) for c, n, o in rows]
 
 
 def _abstract(x: Any) -> Any:
@@ -130,7 +161,8 @@ def register(name: str, fn: Any, args: tuple) -> None:
     shardings of ``args`` (one call's arguments; no buffer is kept)."""
     import jax
     entry = {"name": name, "fn": fn,
-             "args": jax.tree.map(_abstract, args), "table": None}
+             "args": jax.tree.map(_abstract, args), "table": None,
+             "exchange": None}
     with _LOCK:
         _PROGRAMS.append(entry)
 
@@ -140,19 +172,25 @@ def registered() -> List[str]:
         return [p["name"] for p in _PROGRAMS]
 
 
-def scope_table() -> Dict[str, Optional[str]]:
+def scope_table(exchange: bool = False) -> Dict[str, Any]:
     """``{instruction name: phase or None}`` over every registered
-    program, each compiled (once) on the first call that needs it.  A
-    name that two programs place in different phases maps to
+    program, each compiled (once) on the first call that needs it; with
+    ``exchange``, ``{instruction name: (phase or None, under
+    EXCHANGE)}``.  A name that two programs place differently maps to
     ``AMBIGUOUS``."""
     with _LOCK:
         programs = list(_PROGRAMS)
-    table: Dict[str, Optional[str]] = {}
+    table: Dict[str, Any] = {}
     for p in programs:
         if p["table"] is None:
             text = p["fn"].lower(*p["args"]).compile().as_text()
-            p["table"] = {i.name: i.phase for i in instructions(text)}
-        for name, phase in p["table"].items():
-            if table.setdefault(name, phase) != phase:
+            rows = instructions(text)
+            p["exchange"] = {i.name for i in rows if i.exchange}
+            p["table"] = {i.name: i.phase for i in rows}
+        under = p.get("exchange") or ()
+        for name, entry in p["table"].items():
+            if exchange:
+                entry = (entry, name in under)
+            if table.setdefault(name, entry) != entry:
                 table[name] = AMBIGUOUS
     return table

@@ -362,8 +362,13 @@ def snapshot_sweep(state: "lda.SamplerState", key: jax.Array,
     backend = handle.client.backend
 
     # --- snapshot "pull" (paper section 2.3 / 3.4) ---
+    # The collectives themselves run under a nested "exchange" scope, in
+    # the phase they belong to (in-process they are the identity and
+    # compile to nothing).
     with jax.named_scope("ps.pull"):
-        snapshot = handle.pull_all().result()           # [V, K] stale counts
+        with jax.named_scope("exchange"):
+            full = backend.pull_full(handle.storage)
+        snapshot = full.to_dense()                      # [V, K] stale counts
     nk_snap = state.nk.value                            # [K]
 
     # --- alias tables from the snapshot (paper section 3, ref [14]) ---
@@ -432,7 +437,8 @@ def snapshot_sweep(state: "lda.SamplerState", key: jax.Array,
             # and every entry scatter-applied once.  Int adds commute, so
             # the merged counts are bitwise those of the dense formulation.
             if plan.dense is not None:
-                d = backend.reduce(plan.dense)
+                with jax.named_scope("exchange"):
+                    d = backend.reduce(plan.dense)
                 h = d.shape[0]
                 if flat:
                     nwk_dense = nwk_dense.at[:h * cfg.K].add(d.reshape(-1))
@@ -441,8 +447,9 @@ def snapshot_sweep(state: "lda.SamplerState", key: jax.Array,
                 else:
                     nwk_dense = nwk_dense + d
             if plan.coo is not None:
-                c_rows, c_cols, c_vals = (backend.gather_concat(x)
-                                          for x in plan.coo)
+                with jax.named_scope("exchange"):
+                    c_rows, c_cols, c_vals = (backend.gather_concat(x)
+                                              for x in plan.coo)
                 if route.coo_kernel(cfg.use_kernels):
                     from repro.kernels import ops as kops
                     nwk_dense = nwk_dense + kops.delta_apply_coo(
@@ -455,7 +462,9 @@ def snapshot_sweep(state: "lda.SamplerState", key: jax.Array,
                             c_vals)
                     else:
                         nwk_dense = nwk_dense.at[safe, c_cols].add(c_vals)
-            nk = nk + backend.reduce(d_nk)
+            with jax.named_scope("exchange"):
+                d_nk = backend.reduce(d_nk)
+            nk = nk + d_nk
 
         # n_dk stays local: docs are owned by one worker (paper sec. 3),
         # and merges in place -- never through a [D, K] delta per group.

@@ -405,15 +405,15 @@ class TestSpmdPlanes:
         mesh = make_mesh((data, mesh_model), ("data", "model"))
         workers = data * mesh_model
         key = jax.random.PRNGKey(seed)
-        (w, d, valid, ds, dl, z, ndk, nwk,
-         nk) = init_distributed_state(corp, cfg, workers, key)
+        st = init_distributed_state(corp, cfg, workers, key)
         sweep_fn = jax.jit(make_spmd_sweep(mesh, cfg, staleness=1))
-        nwk_val, nk_val = nwk.value, nk
+        z, ndk, nwk_val, nk_val = st.z, st.ndk, st.nwk, st.nk
         for _ in range(sweeps):
             key, sub = jax.random.split(key)
             keys = jax.random.split(sub, workers)
-            z, ndk, nwk_val, nk_val = sweep_fn(w, d, z, valid, ds, dl,
-                                               ndk, nwk_val, nk_val, keys)
+            z, ndk, nwk_val, nk_val = sweep_fn(
+                st.w, st.d, z, st.valid, st.doc_start, st.doc_len, ndk,
+                nwk_val, nk_val, keys)
 
         job = api.LDAJob(corpus=corp, num_topics=8, block_tokens=256,
                          backend=api.SPMD, mesh_model=mesh_model,

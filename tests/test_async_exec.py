@@ -236,16 +236,14 @@ class TestDistributedExecutor:
             num_topics=6)
         cfg = lda.LDAConfig(num_topics=8, vocab_size=200, block_tokens=256,
                             num_shards=model)
-        (w, d, valid, doc_start, doc_len, z, ndk, nwk,
-         nk) = launch_lda.init_distributed_state(
+        st = launch_lda.init_distributed_state(
             corp, cfg, workers, jax.random.PRNGKey(0))
+        w, valid = st.w, st.valid
 
         sweep_fn = jax.jit(launch_lda.make_spmd_sweep(
             mesh, cfg, staleness=1, hot_words=32))
         keys = jax.random.split(jax.random.PRNGKey(1), workers)
-        z2, ndk2, nwk_val2, nk2 = sweep_fn(w, d, z, valid, doc_start,
-                                           doc_len, ndk, nwk.value, nk,
-                                           keys)
+        z2, ndk2, nwk_val2, nk2 = sweep_fn(*st, keys)
         n_tokens = int(valid.sum())
         one = valid.reshape(-1).astype(jnp.int32)
         assert int(nk2.sum()) == n_tokens

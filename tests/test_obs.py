@@ -433,6 +433,79 @@ def test_executor_registers_its_step_once_by_name(lda_state, monkeypatch,
     assert set(table.values()) == set(scopes.PHASES) | {None}
 
 
+COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter",
+               "collective-permute", "all-to-all")
+
+SPMD_SCOPES = """
+import json, sys
+sys.path.insert(0, "src")
+import jax
+from repro import api
+from repro.data import corpus as corpus_mod
+from repro.obs import scopes
+
+corp = corpus_mod.generate_lda_corpus(seed=0, num_docs=80, mean_doc_len=30,
+                                      vocab_size=200, num_topics=6)
+job = api.LDAJob(corpus=corp, num_topics=8, block_tokens=256,
+                 backend=api.SPMD, mesh_model=2, sweeps=1, eval_every=0,
+                 route=api.HybridRoute(hot_words=32, use_kernel=False))
+state, step, _ = api.Session(job, log_fn=lambda *a: None).make_step()
+jax.block_until_ready(step(state, jax.random.PRNGKey(0)).z)
+table = scopes.scope_table(exchange=True)
+(prog,) = scopes._PROGRAMS
+text = prog["fn"].lower(*prog["args"]).compile().as_text()
+print("RESULT " + json.dumps({
+    "registered": scopes.registered(),
+    "rows": [[i.name, i.opcode] + list(table[i.name])
+             for i in scopes.instructions(text)]}))
+"""
+
+
+def test_spmd_scope_table_puts_every_collective_under_exchange():
+    """The SPMD sweep on a (2, 2) mesh of forced host devices: every
+    collective instruction lies under ``exchange``, inside the phase of
+    the pull or the push it serves."""
+    import subprocess
+    import sys
+    import textwrap
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    out = subprocess.run(
+        [sys.executable, "-c", textwrap.dedent(SPMD_SCOPES)],
+        capture_output=True, text=True, timeout=600, env=env, cwd=REPO)
+    assert out.returncode == 0, out.stderr[-4000:]
+    got = json.loads([ln for ln in out.stdout.splitlines()
+                      if ln.startswith("RESULT ")][-1][len("RESULT "):])
+    assert got["registered"] == ["spmd_sweep"]
+    coll = [r for r in got["rows"]
+            if any(r[1].startswith(c) for c in COLLECTIVES)]
+    assert {r[1] for r in coll} >= {"all-gather", "all-reduce"}, coll
+    assert all(r[3] for r in coll), coll
+    assert {r[2] for r in coll} == {"ps.pull", "ps.push"}, coll
+    # what lies under exchange belongs to the pull or the push
+    assert {r[2] for r in got["rows"] if r[3]} <= {"ps.pull", "ps.push"}
+
+
+def test_one_chip_sweep_has_nothing_under_exchange(lda_state, monkeypatch):
+    """In-process the collectives are the identity: the snapshot
+    program compiles nothing under ``exchange``, and its phase table is
+    the one it had."""
+    import jax
+    from repro.obs import scopes
+    from repro.train import async_exec
+
+    monkeypatch.setattr(scopes, "_PROGRAMS", [])
+    _, cfg, state = lda_state(num_docs=80, vocab=128, k=8,
+                              block_tokens=256)
+    step, _ = async_exec.make_executor(
+        state, cfg, async_exec.ExecConfig(hot_words=32))
+    jax.block_until_ready(step(state, jax.random.PRNGKey(0)).z)
+    table = scopes.scope_table(exchange=True)
+    assert table and not any(e for _, e in table.values())
+    assert scopes.scope_table() == {k: p for k, (p, _) in table.items()}
+
+
 def test_scope_table_marks_names_two_programs_place_differently(
         monkeypatch):
     from repro.obs import scopes
@@ -497,7 +570,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PHASE_READERS = {"ps_pull_s.train": 0.05, "alias_tables_s.train": 0.1,
                  "mh_chain_s.train": 0.15, "ps_push_s.train": 0.3,
                  "ndk_merge_s.train": 0.2,
-                 "unscoped_share.train": 100.0 * 0.2 / 1.8175}
+                 "unscoped_share.train": 100.0 * 0.2 / 1.8175,
+                 "ps_push_s.train-spmd": 0.3,
+                 "alias_tables_s.train-spmd": 0.1}
 
 
 def _run_with_ops(monkeypatch, ops):
@@ -569,6 +644,57 @@ def test_phase_readers_read_nothing_from_a_program_without_scopes(
     monkeypatch.setitem(sys.modules, "repro.obs.scopes", None)
     for name in PHASE_READERS:
         assert _read(name, run) is None, name
+
+
+def _exchange_run(monkeypatch, ops, counters=None):
+    """A traced run over 2 sweeps whose program places ``fusion.168``
+    and ``all-gather.3`` under ``exchange``."""
+    from repro.obs import scopes
+
+    run = _run_with_ops(monkeypatch, ops)
+    monkeypatch.setattr(scopes, "scope_table", lambda exchange=False: {
+        "pull.1": ("ps.pull", False), "all-gather.3": ("ps.pull", True),
+        "alias_build.1": ("alias.tables", False),
+        "mh_sample.1": ("mh.chain", False), "fusion.168": ("ps.push", True),
+        "fusion.165": ("ndk.merge", False), "copy.70": (None, False),
+        "fusion.9": scopes.AMBIGUOUS})
+    run.counters.update(counters or {})
+    return run
+
+
+EXCHANGE_OPS = {**SWEEP_OPS, "%all-gather.3 = s32[8,8]{1,0} "
+                "all-gather(%a), dimensions={0}": 0.05}
+
+
+def test_exchange_reader_reads_seconds_per_sweep_under_exchange(
+        monkeypatch):
+    run = _exchange_run(monkeypatch, EXCHANGE_OPS)
+    assert _read("exchange_s.train-spmd", run) == pytest.approx(
+        (0.6 + 0.05) / 2, rel=1e-9)
+
+
+@pytest.mark.parametrize("unknown", ["%mystery.1 = s32[8]{0} add(%a, %b)",
+                                     "%fusion.9 = s32[8]{0} fusion(%a)"])
+def test_exchange_reader_reads_nothing_past_one_percent_unmatched(
+        monkeypatch, unknown):
+    run = _exchange_run(monkeypatch, {**EXCHANGE_OPS, unknown: 0.03})
+    assert _read("exchange_s.train-spmd", run) is None
+
+
+def test_exchange_readers_read_nothing_from_a_program_without_them(
+        monkeypatch):
+    # the scope table of a program with no exchange scope takes no
+    # argument, and its steps give out no exchange bytes
+    run = _run_with_ops(monkeypatch, EXCHANGE_OPS)
+    assert _read("exchange_s.train-spmd", run) is None
+    assert _read("exchange_bytes.train-spmd", run) is None
+
+
+def test_exchange_bytes_reader_sums_the_programs_count(monkeypatch):
+    run = _exchange_run(monkeypatch, EXCHANGE_OPS, {
+        "exchange_bytes_per_sweep": {"pull_all_gather": 10, "hot_psum": 20,
+                                     "cold_all_gather": 30, "nk_psum": 4}})
+    assert _read("exchange_bytes.train-spmd", run) == 64.0
 
 
 # ---------------------------------------------------------------------------

@@ -291,3 +291,50 @@ def test_sweep_loop_has_no_nwk_relayout(one_chip, route):
         .compile().as_text()
     assert "body=" in text
     assert _loop_relayouts(text, V_NYT, K_NYT) == []
+
+
+# ---------------------------------------------------------------------------
+# The SPMD sweep on a described four-chip host
+# ---------------------------------------------------------------------------
+
+def test_spmd_sweep_compiles_with_its_collectives_under_exchange(one_chip):
+    """The SPMD plane's step (``make_spmd_step``) on a (data=2, model=2)
+    mesh of a described v5e:2x2, Pallas path and hybrid push as the
+    four-chip cell runs it: it compiles for the chip, and every
+    collective the compiler put in lies under ``exchange``, inside the
+    pull or the push."""
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding
+
+    from repro import ps
+    from repro.api import session
+    from repro.core import lightlda as lda
+    from repro.obs import scopes
+    from repro.sharding.mesh import make_mesh
+
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    mesh = make_mesh((2, 2), ("data", "model"), devices=topo.devices)
+    v, npad, dmax = 4096, 4 * 2048, 64
+    cfg = lda.LDAConfig(num_topics=SWEEP_K, vocab_size=v, block_tokens=2048,
+                        num_shards=2, use_kernels=True,
+                        kernel_interpret=False)
+    shapes = session.SpmdState(
+        *[(4, npad)] * 4, (4, dmax), (4, dmax), (4, dmax, SWEEP_K),
+        (v, SWEEP_K), (SWEEP_K,))
+    types = session.SpmdState(*[jnp.int32] * 3, jnp.bool_, *[jnp.int32] * 5)
+    state = session.SpmdState(*[
+        jax.ShapeDtypeStruct(s, t, sharding=NamedSharding(mesh, p))
+        for s, t, p in zip(shapes, types, session.spmd_specs())])
+    step = session.make_spmd_step(
+        mesh, cfg, 4, route=ps.HybridRoute(hot_words=512, use_kernel=False))
+    text = step.lower(state, jax.ShapeDtypeStruct((2,), jnp.uint32)) \
+        .compile().as_text()
+    assert "tpu_custom_call" in text
+    coll = [i for i in scopes.instructions(text)
+            if i.opcode.split("-start")[0] in ("all-gather", "all-reduce",
+                                               "reduce-scatter")]
+    assert {i.opcode.split("-start")[0] for i in coll} >= {"all-gather",
+                                                           "all-reduce"}
+    assert all(i.exchange and i.phase in ("ps.pull", "ps.push")
+               for i in coll), [(i.name, i.phase, i.exchange) for i in coll]

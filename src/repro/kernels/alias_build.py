@@ -3,15 +3,24 @@
 The sweep rebuilds alias tables for every vocabulary row from the count
 snapshot (paper section 3, ref [14]).  Construction is a sequential
 two-stack algorithm per row, but it vectorises across the *row* dimension:
-this kernel runs the 2K-step stack loop for a [R, K] tile with all per-row
-state (residual weights, stacks, counters) held in VMEM/registers.
+this kernel runs the stack loop for a [R, K] tile with all per-row state
+(residual weights, stacks, counters) held in VMEM/registers.
+
+Trip count: K-1 steps.  Every real column starts on exactly one stack
+(ns + nl == K) and padded columns on neither.  A step is active only while
+ns > 0 and nl > 0, so only while ns + nl >= 2, and an active step takes
+exactly one off ns + nl: it pops a small, and either that is all
+(ns - 1) or the donor drops below 1 and moves from the large stack to the
+small one (nl - 1, ns unchanged).  After K-1 steps every row is therefore
+at its fixed point; further steps would be inactive in every row.
 
 TPU adaptation: stack pops/pushes become one-hot masked selections over the
 K lane dimension (no scatter/gather hardware needed), exactly like the
 mh_sample kernel's column selects.  The O(K) cost per step makes the loop
-O(K^2) per row -- acceptable because construction is amortized over a whole
-block of token resamples (the LightLDA argument), and the row tile keeps
-the MXU-adjacent VPU busy across 8-128 rows at once.
+O(K^2) per row (K-1 steps of K lanes) -- acceptable because construction
+is amortized over a whole block of token resamples (the LightLDA
+argument), and the row tile keeps the MXU-adjacent VPU busy across 8-128
+rows at once.
 
 Split of labour (mirrors ops.py's pre-gather pattern): the *initial* stack
 layout needs an argsort, which XLA does better than a kernel -- ops.py
@@ -24,7 +33,8 @@ never be emitted as an alias target.
 
 Oracle: ``repro.core.alias.build_alias_rows``, bitwise: both pop the
 same stacks in the same order, and a one-hot lane reduction selects one
-value exactly.
+value exactly.  The oracle runs 2K steps; the K-1 bound above drops only
+steps that change nothing.
 """
 from __future__ import annotations
 
@@ -77,7 +87,8 @@ def _alias_kernel(q_ref, small_ref, large_ref, ns_ref, nl_ref,
     prob0 = jnp.ones((r, kp), jnp.float32)
     state = (q_ref[...], prob0, iota, small_ref[...], large_ref[...],
              ns_ref[...], nl_ref[...])
-    state = jax.lax.fori_loop(0, 2 * num_cols, body, state)
+    # ns + nl == num_cols falls by one per active step (module docstring)
+    state = jax.lax.fori_loop(0, max(num_cols - 1, 0), body, state)
     _, prob, alias, _, _, _, _ = state
     prob_ref[...] = jnp.clip(prob, 0.0, 1.0)
     alias_ref[...] = alias

@@ -158,11 +158,15 @@ def alias_build(weights, *, tile_rows: int = 128,
     weights to mean 1, build the initial small/large stack layouts with an
     argsort, pad K to the lane width with exactly-1.0 entries (excluded
     from both stacks -> provably never emitted as alias targets) and rows
-    to the tile.  The kernel runs the sequential 2K retirement loop.
+    to the tile.  The kernel runs the sequential retirement loop for
+    ``k - 1`` steps: every real column starts on exactly one stack
+    (``ns + nl == k``), and each active step takes exactly one off that
+    sum, so no step after the ``k - 1``-th can be active in any row.
 
     Bitwise ``alias.build_alias_rows``: the same stacks in the same order
-    and the same retirement steps, so a kernel sweep stays bit-identical
-    to the oracle sweep.
+    and the same active retirement steps (the oracle's remaining steps up
+    to 2K change nothing), so a kernel sweep stays bit-identical to the
+    oracle sweep.
     """
     interpret = _resolve_interpret(interpret)
     v, k = weights.shape

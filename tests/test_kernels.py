@@ -174,6 +174,37 @@ class TestHybridDeltaParity:
                                       np.asarray(got_kernel))
 
 
+def _alias_weights(kind, v, k, key):
+    """[v, k] weight rows of one shape of distribution."""
+    ks = jax.random.split(key, 3)
+    if kind == "counts":
+        # Poisson counts, plus rows with weights exactly at the mean
+        # (q == 1 joins the large stack in the kernel and the oracle)
+        cnt = jax.random.poisson(ks[0], 0.5, (v, k)).astype(jnp.float32)
+        at_mean = jnp.ones((3, k)).at[:, 0].set(2.0).at[:, 1:3].set(0.5)
+        return jnp.concatenate([cnt + 0.01, at_mean[:, :k]])
+    if kind == "one_heavy":
+        light = jax.random.uniform(ks[0], (v, k), minval=0.5, maxval=1.0)
+        heavy = 10.0 * k * jax.random.uniform(ks[1], (v,), minval=1.0,
+                                              maxval=100.0)
+        col = jnp.arange(v) % k
+        return light.at[jnp.arange(v), col].set(heavy)
+    if kind == "gamma":
+        return jax.random.gamma(ks[0], 0.05, (v, k))
+    if kind == "at_mean":
+        return jnp.ones((v, k))
+    if kind == "one_nonzero":
+        col = jax.random.randint(ks[0], (v,), 0, k)
+        val = jax.random.uniform(ks[1], (v,), minval=0.1, maxval=10.0)
+        return jnp.zeros((v, k)).at[jnp.arange(v), col].set(val)
+    if kind == "mixed":
+        kinds = ("counts", "one_heavy", "gamma", "at_mean", "one_nonzero")
+        parts = [_alias_weights(kd, v, k, kk)
+                 for kd, kk in zip(kinds, jax.random.split(ks[2], len(kinds)))]
+        return jnp.concatenate(parts)
+    raise ValueError(kind)
+
+
 class TestAliasBuildKernel:
     @pytest.mark.parametrize("v,k", [
         (16, 8),
@@ -196,15 +227,27 @@ class TestAliasBuildKernel:
         # alias targets must never point at padded columns
         assert int(np.asarray(got.alias).max()) < k
 
-    @pytest.mark.parametrize("v,k", [(64, 33), (37, 130)])
-    def test_bitwise_matches_oracle(self, v, k):
-        """Same prob and alias arrays as the jnp Vose build, including rows
-        with weights exactly at the mean (q == 1 joins the large stack in
-        both), so a kernel sweep can use it and stay bit-identical."""
-        key = jax.random.PRNGKey(v + k)
-        cnt = jax.random.poisson(key, 0.5, (v, k)).astype(jnp.float32)
-        at_mean = jnp.ones((3, k)).at[:, 0].set(2.0).at[:, 1:3].set(0.5)
-        w = jnp.concatenate([cnt + 0.01, at_mean])
+    @pytest.mark.parametrize("v,k,kind", [
+        pytest.param(64, 33, "counts", id="64-33"),
+        pytest.param(37, 130, "counts", id="37-130"),
+        # one donor feeds every other column: all K-1 steps are active
+        pytest.param(16, 33, "one_heavy", id="one_heavy-16-33"),
+        pytest.param(16, 200, "one_heavy", id="one_heavy-16-200"),
+        pytest.param(16, 200, "gamma", id="gamma-16-200"),
+        pytest.param(16, 200, "at_mean", id="at_mean-16-200"),
+        pytest.param(16, 200, "one_nonzero", id="one_nonzero-16-200"),
+        pytest.param(9, 1, "counts", id="counts-9-1"),
+        pytest.param(9, 1, "one_nonzero", id="one_nonzero-9-1"),
+        # the benchmark cells' K, every kind of row in one build
+        pytest.param(10, 1024, "mixed", id="mixed-10-1024"),
+    ])
+    def test_bitwise_matches_oracle(self, v, k, kind):
+        """Same prob and alias arrays as the jnp Vose build, so a kernel
+        sweep can use it and stay bit-identical.  The kernel stops its
+        retirement loop after K-1 steps, the oracle runs 2K: rows whose
+        last active step is the (K-1)-th (one heavy donor, one nonzero
+        weight) hold it to that bound."""
+        w = _alias_weights(kind, v, k, jax.random.PRNGKey(v + k))
         got = kops.alias_build(w, tile_rows=32)
         ref = kref.alias_build_ref(w)
         np.testing.assert_array_equal(np.asarray(got.prob),

@@ -158,7 +158,7 @@ def spmd_specs() -> SpmdState:
 
 
 def make_spmd_sweep(mesh, cfg: "lda.LDAConfig", staleness: int = 0,
-                    hot_words=None, route: Optional["ps.PushRoute"] = None):
+                    route: Optional["ps.PushRoute"] = None):
     """shard_map'd sweep: tokens split over (data, model); n_wk rows cyclic
     over model (the servers); deltas psum'd over all workers.  The count
     tables enter through an SPMD-backed ``PSClient`` -- the sweep gets its
@@ -166,9 +166,10 @@ def make_spmd_sweep(mesh, cfg: "lda.LDAConfig", staleness: int = 0,
     handle's backend, not from axis kwargs.  The executor schedule knobs
     thread through: with ``staleness`` s, each worker merges (and psums)
     deltas once per group of s+1 token blocks -- fewer, larger
-    collectives -- and ``route`` (or the legacy ``hot_words``) selects the
-    push policy (dense / coordinate / hybrid).  Arguments are the
-    ``SpmdState`` fields, then the ``[workers, 2]`` per-worker keys."""
+    collectives -- and ``route`` selects the push policy (dense /
+    coordinate / hybrid; ``ps.route_for`` maps a hot-word count onto
+    it).  Arguments are the ``SpmdState`` fields, then the ``[workers,
+    2]`` per-worker keys."""
     from jax.sharding import PartitionSpec as P
 
     client = ps.client_for(cfg, axis_name=("data", "model"),
@@ -179,8 +180,7 @@ def make_spmd_sweep(mesh, cfg: "lda.LDAConfig", staleness: int = 0,
             w[0], d[0], z[0], valid[0], doc_start[0], doc_len[0],
             client.wrap_matrix(nwk_local, cfg.V),
             client.wrap_vector(nk), ndk[0])
-        out = lda.sweep(state, keys[0], cfg,
-                        staleness=staleness, hot_words=hot_words,
+        out = lda.sweep(state, keys[0], cfg, staleness=staleness,
                         route=route)
         return (out.z[None], out.ndk[None], out.nwk.value, out.nk.value)
 

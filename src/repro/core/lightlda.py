@@ -307,6 +307,23 @@ def build_alias_tables(weights: jax.Array, use_kernels: bool = False,
     return alias_mod.build_alias_rows(weights)
 
 
+def sample_chain(rng: MHRandoms, z0: jax.Array, nwk_rows: jax.Array,
+                 ndk_rows: jax.Array, nk: jax.Array, aprob_rows: jax.Array,
+                 aalias_rows: jax.Array, cfg: LDAConfig, *,
+                 use_kernels: bool, interpret: Optional[bool],
+                 frozen: bool = False) -> jax.Array:
+    """The MH chain over pre-gathered rows: the Pallas kernel under
+    ``use_kernels`` (bitwise ``mh_chain``), else ``mh_chain`` itself.
+    ``frozen`` selects the fold-in semantics (see ``_posterior_terms``)."""
+    if use_kernels:
+        from repro.kernels import ops as kops
+        return kops.mh_sample(rng, z0, nwk_rows, ndk_rows, nk, aprob_rows,
+                              aalias_rows, cfg, interpret=interpret,
+                              frozen=frozen)
+    return mh_chain(rng, z0, nwk_rows, ndk_rows, nk, aprob_rows, aalias_rows,
+                    cfg, frozen=frozen)
+
+
 class FrozenModel(NamedTuple):
     """Immutable model snapshot for inference.
 
@@ -361,13 +378,10 @@ def sample_tokens_frozen(model: FrozenModel, rng: MHRandoms, z0: jax.Array,
         aprob_rows = jnp.take(model.aprob, w, axis=0)
         aalias_rows = jnp.take(model.aalias, w, axis=0)
     with jax.named_scope("mh.chain"):
-        if use_kernels:
-            from repro.kernels import ops as kops
-            return kops.mh_sample(rng, z0, nwk_rows, ndk_rows, model.nk,
-                                  aprob_rows, aalias_rows, cfg, frozen=True,
-                                  interpret=interpret)
-        return mh_chain(rng, z0, nwk_rows, ndk_rows, model.nk,
-                        aprob_rows, aalias_rows, cfg, frozen=True)
+        return sample_chain(rng, z0, nwk_rows, ndk_rows, model.nk,
+                            aprob_rows, aalias_rows, cfg,
+                            use_kernels=use_kernels, interpret=interpret,
+                            frozen=True)
 
 
 # Dense delta aggregation (paper section 3.3) lives in ps/routes.py now:
@@ -381,32 +395,24 @@ def sample_tokens_frozen(model: FrozenModel, rng: MHRandoms, z0: jax.Array,
 # ---------------------------------------------------------------------------
 
 def sweep(state: SamplerState, key: jax.Array, cfg: LDAConfig,
-          axis_name: Optional[str] = None,
-          model_axis: Optional[str] = None,
           staleness: int = 0,
-          hot_words: Optional[int] = None,
           route: Optional["ps.PushRoute"] = None) -> SamplerState:
     """Resample every token once (one Gibbs sweep == one paper "iteration").
 
     The SPMD collectives come from ``state.nwk``'s client backend
     (``repro.ps``): wrap the counts with ``PSClient.create(axis_name=...,
-    model_axis=...)`` to run under shard_map.  The legacy
-    ``axis_name``/``model_axis`` kwargs override the handle's backend for
-    callers that have not migrated.
+    model_axis=...)`` to run under shard_map.
 
     Routed through the asynchronous executor
     (``train.async_exec.snapshot_sweep``); ``staleness`` selects the
-    bounded-staleness schedule and ``route`` (or the legacy ``hot_words``
-    knob) the push policy -- ``ps.DenseRoute`` / ``ps.CooRoute`` /
-    ``ps.HybridRoute``.  The defaults reproduce the classic per-block
-    synchronous schedule exactly -- single-device defaults are the oracle
-    used in tests.
+    bounded-staleness schedule and ``route`` the push policy --
+    ``ps.DenseRoute`` (the default) / ``ps.CooRoute`` / ``ps.HybridRoute``.
+    The defaults reproduce the classic per-block synchronous schedule
+    exactly -- single-device defaults are the oracle used in tests.
     """
     from repro.train import async_exec
-    return async_exec.snapshot_sweep(state, key, cfg, axis_name=axis_name,
-                                     model_axis=model_axis,
-                                     staleness=staleness,
-                                     hot_words=hot_words, route=route)
+    return async_exec.snapshot_sweep(state, key, cfg, staleness=staleness,
+                                     route=route)
 
 
 def train(state: SamplerState, key: jax.Array, cfg: LDAConfig,
@@ -490,7 +496,6 @@ def block_token_index(w: np.ndarray, valid: np.ndarray, rows_per_block: int,
 def sweep_blocked(state: SamplerState, key: jax.Array, cfg: LDAConfig,
                   block_idx: jax.Array, block_valid: jax.Array,
                   rows_per_block: int, staleness: int = 0,
-                  hot_words: Optional[int] = None,
                   route: Optional["ps.PushRoute"] = None) -> SamplerState:
     """One sweep processing the model in pulled blocks (paper section 3.4).
 
@@ -498,16 +503,15 @@ def sweep_blocked(state: SamplerState, key: jax.Array, cfg: LDAConfig,
     (``train.async_exec.pipelined_sweep``): double-buffered block pulls
     (``PullHandle`` futures), a bounded-staleness merge schedule
     (``staleness`` block deltas may be in flight while a block samples)
-    and a declarative push policy (``route``, or the legacy ``hot_words``
-    knob for the hybrid dense/sparse split).  The defaults reproduce the
+    and a declarative push policy (``route``; ``ps.route_for`` maps a
+    hot-word count onto the hybrid split).  The defaults reproduce the
     synchronous schedule of ``sweep_blocked_ref`` bitwise (asserted in
     tests/test_async_exec.py).
     """
     from repro.train import async_exec
     return async_exec.pipelined_sweep(state, key, cfg, block_idx,
                                       block_valid, rows_per_block,
-                                      staleness=staleness,
-                                      hot_words=hot_words, route=route)
+                                      staleness=staleness, route=route)
 
 
 def sweep_blocked_ref(state: SamplerState, key: jax.Array, cfg: LDAConfig,

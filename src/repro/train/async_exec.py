@@ -38,6 +38,11 @@ paper's split: hot prefix dense, cold tail as the 100k-reassignment
 message).  All routes are integer additions underneath, so the choice
 never changes results, only traffic shape.
 
+Every executor runs the same block step -- ``resample_block`` (gather,
+MH chain), ``topic_delta`` and ``merge_doc_counts`` -- and the blocked and
+tiered executors the same group, ``blocked_group``; each keeps only where
+its rows come from and how it pushes n_wk.
+
 Entry points:
   * ``pipelined_sweep``  -- the blocked model-parallel executor (the
     generalisation of ``lightlda.sweep_blocked``; worker memory
@@ -60,6 +65,7 @@ import numpy as np
 from repro import obs as _obs
 from repro import ps
 from repro.core import lightlda as lda
+from repro.core.alias import AliasTable
 from repro.obs import ObsConfig
 from repro.obs import scopes as _scopes
 from repro.obs.trace import _block
@@ -124,41 +130,104 @@ def effective_staleness(n_blocks: int, staleness: int) -> int:
 
 
 # ---------------------------------------------------------------------------
-# Shared pieces.
+# The block step every executor runs.  Plain functions, traced inline: the
+# phase scopes they open land in the caller's name stack unchanged.
 # ---------------------------------------------------------------------------
 
-def token_deltas(d_b, z_old, z_new, changed, num_docs: int, num_topics: int
-                 ) -> Tuple[jax.Array, jax.Array]:
-    """The worker-local halves of a reassignment batch: (d_nk [K],
-    d_ndk [num_docs, K]).  These never route -- ``n_k`` reduces over
-    workers, ``n_dk`` stays with the document's owner (paper section 3)."""
-    amt = changed.astype(jnp.int32)
-    d_nk = (jnp.zeros((num_topics,), jnp.int32)
-            .at[z_old].add(-amt).at[z_new].add(amt))
-    d_ndk = (jnp.zeros((num_docs, num_topics), jnp.int32)
-             .at[d_b, z_old].add(-amt).at[d_b, z_new].add(amt))
-    return d_nk, d_ndk
+def proposal_tables(rows: jax.Array, nk: jax.Array, cfg: "lda.LDAConfig"
+                    ) -> AliasTable:
+    """Alias tables of the word proposal q_w(k) ∝ (n_wk + β)/(n_k + Vβ)
+    for a set of n_wk rows (the sweep-start snapshot, or one group's
+    rows).  The kernel path builds them with the Pallas kernel, bitwise
+    the jnp construction (paper section 3, ref [14])."""
+    with jax.named_scope("alias.tables"):
+        weights = (rows.astype(jnp.float32) + cfg.beta) / (
+            nk.astype(jnp.float32)[None, :] + cfg.V * cfg.beta)
+        return lda.build_alias_tables(weights, cfg.use_kernels,
+                                      cfg.kernel_interpret)
 
 
-def hybrid_count_deltas(w_b, d_b, z_old, z_new, valid_b, num_docs: int,
-                        hot_words: int, cfg: "lda.LDAConfig",
-                        use_kernel: bool = False,
-                        interpret: Optional[bool] = None
-                        ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Block-level count deltas with the hybrid hot/cold word split.
+def resample_block(key: jax.Array, cfg: "lda.LDAConfig", rows: jax.Array,
+                   table: AliasTable, row_ids: jax.Array, d_b: jax.Array,
+                   z0: jax.Array, valid: jax.Array, z_flat: jax.Array,
+                   ndk: jax.Array, nk: jax.Array, doc_start: jax.Array,
+                   doc_len: jax.Array) -> jax.Array:
+    """Resample a batch of tokens against stale counts: gather each
+    token's n_wk row (``rows[row_ids]``), n_dk row and alias-table rows,
+    then run the MH chain.  Returns the new assignments, ``z0`` where
+    ``valid`` is False."""
+    with jax.named_scope("ps.pull"):
+        nwk_rows = jnp.take(rows, row_ids, axis=0)
+        ndk_rows = jnp.take(ndk, d_b, axis=0)
+        aprob_rows = jnp.take(table.prob, row_ids, axis=0)
+        aalias_rows = jnp.take(table.alias, row_ids, axis=0)
+    with jax.named_scope("mh.chain"):
+        doc_draw = lda.make_doc_draw(None, d_b, z_flat, doc_start, doc_len,
+                                     cfg)
+        rng = lda.draw_mh_randoms(key, doc_draw, z0.shape[0], cfg)
+        z_new = lda.sample_chain(rng, z0, nwk_rows, ndk_rows, nk, aprob_rows,
+                                 aalias_rows, cfg,
+                                 use_kernels=cfg.use_kernels,
+                                 interpret=cfg.kernel_interpret)
+        return jnp.where(valid, z_new, z0)
 
-    Legacy entry point, now a thin wrapper over ``ps.route_for``: the
-    top-``hot_words`` words aggregate densely, the cold tail as coordinate
-    deltas.  Same (d_nwk [V,K], d_nk [K], d_ndk [D,K]) contract and --
-    addition being exact on int32 -- the same values for every ``H``.
+
+def topic_delta(z0: jax.Array, z_new: jax.Array, amt: jax.Array,
+                num_topics: int) -> jax.Array:
+    """The ``[K]`` n_k delta of a batch of reassignments (``amt`` is 1
+    where a token moved, else 0)."""
+    return (jnp.zeros((num_topics,), jnp.int32)
+            .at[z0].add(-amt).at[z_new].add(amt))
+
+
+def merge_doc_counts(ndk: jax.Array, d_b: jax.Array, z0: jax.Array,
+                     z_new: jax.Array, amt: jax.Array) -> jax.Array:
+    """Apply a batch of reassignments to the worker-local n_dk in place.
+    n_dk never routes: each document is owned by one worker (paper
+    section 3), so it merges without a ``[D, K]`` delta."""
+    return ndk.at[d_b, z0].add(-amt).at[d_b, z_new].add(amt)
+
+
+def blocked_group(rows: jax.Array, nk: jax.Array, ndk: jax.Array,
+                  z_flat: jax.Array, idx: jax.Array, bval: jax.Array,
+                  start, key: jax.Array, *, state: "lda.SamplerState",
+                  layout, n_rows: int, cfg: "lda.LDAConfig",
+                  route: ps.PushRoute):
+    """One group of the blocked schedule: resample the tokens ``idx``
+    (valid where ``bval``) whose words own the physical rows ``[start,
+    start + n_rows)``, all against the group-start counts, and merge.
+
+    ``rows`` are those n_wk rows; alias tables are built for them alone
+    (worker memory O(n_rows x K)).  ``state`` supplies the token arrays.
+    Returns ``(rows + delta, nk, ndk, z_flat, local, amt)``: ``local`` is
+    each token's row within the group and ``amt`` 1 where it moved.
     """
-    changed = (z_old != z_new) & valid_b
-    route = ps.route_for(hot_words, cfg.V)
-    d_nwk = route.block_delta(
-        ps.Reassign(w_b, w_b, z_old, z_new, changed), cfg.V, cfg.K,
-        use_kernels=use_kernel, prefix_rows=True, interpret=interpret)
-    d_nk, d_ndk = token_deltas(d_b, z_old, z_new, changed, num_docs, cfg.K)
-    return d_nwk, d_nk, d_ndk
+    table = proposal_tables(rows, nk, cfg)
+    with jax.named_scope("ps.pull"):
+        wb = jnp.take(state.w, idx)
+        db = jnp.take(state.d, idx)
+        z0 = jnp.take(z_flat, idx)
+        local = jnp.clip(layout.to_physical(wb) - start, 0, n_rows - 1)
+    z_new = resample_block(key, cfg, rows, table, local, db, z0, bval, z_flat,
+                           ndk, nk, state.doc_start, state.doc_len)
+
+    # the route materialises the group-local delta (hot dense slice, cold
+    # COO -- whatever the policy says)
+    with jax.named_scope("ps.push"):
+        changed = (z_new != z0) & bval
+        d_rows = route.block_delta(
+            ps.Reassign(rows=local, words=wb, z_old=z0, z_new=z_new,
+                        changed=changed),
+            n_rows, cfg.K, use_kernels=cfg.use_kernels,
+            interpret=cfg.kernel_interpret)
+        rows = rows + d_rows
+        amt = changed.astype(jnp.int32)
+        nk = nk + topic_delta(z0, z_new, amt, cfg.K)
+
+    with jax.named_scope("ndk.merge"):
+        ndk = merge_doc_counts(ndk, db, z0, z_new, amt)
+        z_flat = z_flat.at[idx].add(jnp.where(bval, z_new - z0, 0))
+    return rows, nk, ndk, z_flat, local, amt
 
 
 # ---------------------------------------------------------------------------
@@ -169,7 +238,6 @@ def pipelined_sweep(state: "lda.SamplerState", key: jax.Array,
                     cfg: "lda.LDAConfig", block_idx: jax.Array,
                     block_valid: jax.Array, rows_per_block: int,
                     staleness: int = 0,
-                    hot_words: Optional[int] = None,
                     route: Optional[ps.PushRoute] = None
                     ) -> "lda.SamplerState":
     """One staleness-bounded, double-buffered, routed blocked sweep.
@@ -180,14 +248,14 @@ def pipelined_sweep(state: "lda.SamplerState", key: jax.Array,
       1. the group's ``n_wk`` rows arrive by awaiting the previous step's
          ``PullHandle``; the *next* group's pull is issued immediately
          (``MatrixHandle.pull_block``), overlapping the sampling below;
-      2. alias tables are built for the group's rows only (worker memory
-         O(group x K));
-      3. all of the group's tokens are resampled in one fused MH chain
-         against the group-start (bounded-stale) counts;
-      4. deltas merge at the group boundary: the ``PushRoute``
-         materialises the group-local delta (dense / COO-kernel / hybrid)
-         and ``MatrixHandle.store_block`` writes the owned rows back;
-         ``n_k``/``n_dk``/``z`` merge through duplicate-tolerant adds.
+      2. ``blocked_group`` builds alias tables for the group's rows only
+         (worker memory O(group x K)), resamples all of the group's
+         tokens in one fused MH chain against the group-start
+         (bounded-stale) counts, and merges the deltas at the group
+         boundary: the ``PushRoute`` (``ps.DenseRoute`` when None)
+         materialises the group-local delta and ``n_k``/``n_dk``/``z``
+         merge through duplicate-tolerant adds;
+      3. ``MatrixHandle.store_block`` writes the owned rows back.
 
     ``staleness=0`` is bitwise-identical to ``lightlda.sweep_blocked_ref``.
     """
@@ -201,7 +269,7 @@ def pipelined_sweep(state: "lda.SamplerState", key: jax.Array,
     n_groups = n_blocks // group
     grp_rows = group * rpb
     if route is None:
-        route = ps.route_for(hot_words, cfg.V)
+        route = ps.DenseRoute()
 
     # Fuse each group of s+1 consecutive blocks into one scan step.  (The
     # host-side ``make_executor`` instead builds the token index directly
@@ -211,72 +279,25 @@ def pipelined_sweep(state: "lda.SamplerState", key: jax.Array,
         gidx = block_idx.reshape(n_groups, group * cap)
         gval = block_valid.reshape(n_groups, group * cap)
         groups = jnp.arange(n_groups)
-    gcap = group * cap
 
     def group_body(carry, inp):
         nwk, nk, ndk, z_flat, pulled = carry
         grp, key_g = inp
 
-        # 1. double buffer: await this group's prefetched rows, issue the
+        # double buffer: await this group's prefetched rows, issue the
         # next group's pull before sampling.  Exact, not approximate: this
         # group's write-back only touches its own physical rows, so the
         # in-flight pull cannot be invalidated.
         with jax.named_scope("ps.pull"):
             rows = pulled.result()
             pulled_next = nwk.pull_block((grp + 1) % n_groups, grp_rows)
-
-        # 2. alias tables for the group's rows only
-        with jax.named_scope("alias.tables"):
-            weights = (rows.astype(jnp.float32) + cfg.beta) / (
-                nk.astype(jnp.float32)[None, :] + cfg.V * cfg.beta)
-            table = lda.build_alias_tables(weights, cfg.use_kernels,
-                                           cfg.kernel_interpret)
-
-        # 3. fused resample of the group's tokens against the stale view
-        with jax.named_scope("ps.pull"):
-            idx = gidx[grp]
-            vb = gval[grp]
-            wb = jnp.take(state.w, idx)
-            db = jnp.take(state.d, idx)
-            z0 = jnp.take(z_flat, idx)
-            local = jnp.clip(layout.to_physical(wb) - grp * grp_rows, 0,
-                             grp_rows - 1)
-            nwk_rows = jnp.take(rows, local, axis=0)
-            ndk_rows = jnp.take(ndk, db, axis=0)
-            aprob = jnp.take(table.prob, local, axis=0)
-            aalias = jnp.take(table.alias, local, axis=0)
-        with jax.named_scope("mh.chain"):
-            doc_draw = lda.make_doc_draw(None, db, z_flat, state.doc_start,
-                                         state.doc_len, cfg)
-            rng = lda.draw_mh_randoms(key_g, doc_draw, gcap, cfg)
-            if cfg.use_kernels:
-                from repro.kernels import ops as kops
-                z_new = kops.mh_sample(rng, z0, nwk_rows, ndk_rows, nk,
-                                       aprob, aalias, cfg,
-                                       interpret=cfg.kernel_interpret)
-            else:
-                z_new = lda.mh_chain(rng, z0, nwk_rows, ndk_rows, nk, aprob,
-                                     aalias, cfg)
-            z_new = jnp.where(vb, z_new, z0)
-
-        # 4. group-boundary merge: the route materialises the group-local
-        # delta (hot dense slice, cold COO -- whatever the policy says);
-        # store_block writes the exclusively-owned rows back.
+            idx, vb = gidx[grp], gval[grp]
+        rows, nk, ndk, z_flat, _, _ = blocked_group(
+            rows, nk, ndk, z_flat, idx, vb, grp * grp_rows, key_g,
+            state=state, layout=layout, n_rows=grp_rows, cfg=cfg,
+            route=route)
         with jax.named_scope("ps.push"):
-            changed = (z_new != z0) & vb
-            d_rows = route.block_delta(
-                ps.Reassign(rows=local, words=wb, z_old=z0, z_new=z_new,
-                            changed=changed),
-                grp_rows, cfg.K, use_kernels=cfg.use_kernels,
-                interpret=cfg.kernel_interpret)
-            nwk = nwk.store_block(grp, rows + d_rows, grp_rows)
-            amt = changed.astype(jnp.int32)
-            nk = nk + (jnp.zeros((cfg.K,), jnp.int32)
-                       .at[z0].add(-amt).at[z_new].add(amt))
-
-        with jax.named_scope("ndk.merge"):
-            ndk = ndk.at[db, z0].add(-amt).at[db, z_new].add(amt)
-            z_flat = z_flat.at[idx].add(jnp.where(vb, z_new - z0, 0))
+            nwk = nwk.store_block(grp, rows, grp_rows)
         return (nwk, nk, ndk, z_flat, pulled_next), ()
 
     with jax.named_scope("mh.chain"):
@@ -319,10 +340,7 @@ def nwk_carry_layout(route: ps.PushRoute, num_rows: int, num_topics: int,
 
 
 def snapshot_sweep(state: "lda.SamplerState", key: jax.Array,
-                   cfg: "lda.LDAConfig",
-                   axis_name=None, model_axis=None,
-                   staleness: int = 0,
-                   hot_words: Optional[int] = None,
+                   cfg: "lda.LDAConfig", staleness: int = 0,
                    route: Optional[ps.PushRoute] = None
                    ) -> "lda.SamplerState":
     """One full-snapshot sweep with staleness-grouped token blocks.
@@ -330,15 +348,15 @@ def snapshot_sweep(state: "lda.SamplerState", key: jax.Array,
     Identical to the classic ``lightlda.sweep`` schedule except that
     groups of ``staleness + 1`` consecutive token blocks are resampled as
     one fused step against the group-start counts, and the group's deltas
-    (shaped by ``route``) merge -- including the cross-worker "push"
-    reduction -- once per group instead of per block.
+    (shaped by ``route``, ``ps.DenseRoute`` when None) merge -- including
+    the cross-worker "push" reduction -- once per group instead of per
+    block.
 
     The collectives come from ``state.nwk``'s client backend: an
     ``SpmdBackend`` turns the snapshot pull into an all-gather over the
     server axis and the delta merge into one ``psum`` over the worker
-    axes; in-process both are the identity.  The legacy
-    ``axis_name``/``model_axis`` kwargs override the handle's backend.
-    ``staleness=0`` reproduces the per-block schedule exactly.
+    axes; in-process both are the identity.  ``staleness=0`` reproduces
+    the per-block schedule exactly.
 
     The n_wk aggregate rides the scan in ``nwk_carry_layout``'s layout
     and is reshaped to ``[V, K]`` once, after the scan.
@@ -350,15 +368,9 @@ def snapshot_sweep(state: "lda.SamplerState", key: jax.Array,
     n_groups = nblocks // group
     gtok = group * cfg.block_tokens
     if route is None:
-        route = ps.route_for(hot_words, cfg.V)
+        route = ps.DenseRoute()
     flat = nwk_carry_layout(route, cfg.V, cfg.K, cfg.use_kernels) == "flat"
-
-    # --- backend: the handle's client, unless legacy kwargs override ---
     handle = state.nwk
-    if axis_name is not None or model_axis is not None:
-        client = handle.client.with_backend(
-            ps.SpmdBackend(axis_name=axis_name, model_axis=model_axis))
-        handle = ps.MatrixHandle(handle.storage, client, handle.route)
     backend = handle.client.backend
 
     # --- snapshot "pull" (paper section 2.3 / 3.4) ---
@@ -370,16 +382,7 @@ def snapshot_sweep(state: "lda.SamplerState", key: jax.Array,
             full = backend.pull_full(handle.storage)
         snapshot = full.to_dense()                      # [V, K] stale counts
     nk_snap = state.nk.value                            # [K]
-
-    # --- alias tables from the snapshot (paper section 3, ref [14]) ---
-    # The kernel path builds them with the Pallas kernel, bitwise the
-    # jnp construction: the kernel sweep stays bit-identical to the
-    # oracle sweep, and at real V x K the jnp build dominates a sweep.
-    with jax.named_scope("alias.tables"):
-        weights = (snapshot.astype(jnp.float32) + cfg.beta) / (
-            nk_snap.astype(jnp.float32)[None, :] + cfg.V * cfg.beta)
-        table = lda.build_alias_tables(weights, cfg.use_kernels,
-                                       cfg.kernel_interpret)
+    table = proposal_tables(snapshot, nk_snap, cfg)
 
     with jax.named_scope("ps.pull"):
         w_groups = state.w.reshape(n_groups, gtok)
@@ -391,32 +394,16 @@ def snapshot_sweep(state: "lda.SamplerState", key: jax.Array,
         z_flat, ndk, nwk_dense, nk = carry
         grp, key_g = inp
 
-        # Pre-gather per-token rows (the "pull" of the rows this group
-        # needs).  The word rows come from the sweep-start snapshot; the
-        # doc rows and n_k are stale by at most ``staleness`` blocks.
+        # The word rows come from the sweep-start snapshot; the doc rows
+        # and n_k are stale by at most ``staleness`` blocks.
         with jax.named_scope("ps.pull"):
             w_b = w_groups[grp]
             d_b = d_groups[grp]
             valid_b = v_groups[grp]
             z0 = jax.lax.dynamic_slice_in_dim(z_flat, grp * gtok, gtok)
-            nwk_rows = jnp.take(snapshot, w_b, axis=0)
-            ndk_rows = jnp.take(ndk, d_b, axis=0)
-            aprob_rows = jnp.take(table.prob, w_b, axis=0)
-            aalias_rows = jnp.take(table.alias, w_b, axis=0)
-
-        with jax.named_scope("mh.chain"):
-            doc_draw = lda.make_doc_draw(None, d_b, z_flat, state.doc_start,
-                                         state.doc_len, cfg)
-            rng = lda.draw_mh_randoms(key_g, doc_draw, gtok, cfg)
-            if cfg.use_kernels:
-                from repro.kernels import ops as kops
-                z_new = kops.mh_sample(rng, z0, nwk_rows, ndk_rows, nk,
-                                       aprob_rows, aalias_rows, cfg,
-                                       interpret=cfg.kernel_interpret)
-            else:
-                z_new = lda.mh_chain(rng, z0, nwk_rows, ndk_rows, nk,
-                                     aprob_rows, aalias_rows, cfg)
-            z_new = jnp.where(valid_b, z_new, z0)
+        z_new = resample_block(key_g, cfg, snapshot, table, w_b, d_b, z0,
+                               valid_b, z_flat, ndk, nk, state.doc_start,
+                               state.doc_len)
 
         # --- routed delta aggregation + group-boundary merge (3.3) ---
         with jax.named_scope("ps.push"):
@@ -427,8 +414,7 @@ def snapshot_sweep(state: "lda.SamplerState", key: jax.Array,
                 cfg.V, cfg.K, use_kernels=cfg.use_kernels, prefix_rows=True,
                 interpret=cfg.kernel_interpret)
             amt = changed.astype(jnp.int32)
-            d_nk = (jnp.zeros((cfg.K,), jnp.int32)
-                    .at[z0].add(-amt).at[z_new].add(amt))
+            d_nk = topic_delta(z0, z_new, amt, cfg.K)
             # SPMD "push": merge each half of the plan over the workers
             # once per group (identity in-process).  The dense part -- the
             # hybrid's [H, K] hot prefix, never padded to [V, K] -- sums
@@ -466,10 +452,8 @@ def snapshot_sweep(state: "lda.SamplerState", key: jax.Array,
                 d_nk = backend.reduce(d_nk)
             nk = nk + d_nk
 
-        # n_dk stays local: docs are owned by one worker (paper sec. 3),
-        # and merges in place -- never through a [D, K] delta per group.
         with jax.named_scope("ndk.merge"):
-            ndk = ndk.at[d_b, z0].add(-amt).at[d_b, z_new].add(amt)
+            ndk = merge_doc_counts(ndk, d_b, z0, z_new, amt)
             z_flat = jax.lax.dynamic_update_slice_in_dim(
                 z_flat, z_new, grp * gtok, axis=0)
         return (z_flat, ndk, nwk_dense, nk), ()
@@ -697,8 +681,9 @@ def make_tiered_executor(state: "lda.SamplerState", cfg: "lda.LDAConfig",
     the owned rows back -- but driven from a *host* loop: the tier's
     residency maps and cold memmap are host state, so the handle cannot
     ride a jitted scan carry.  The per-block math is one jitted inner
-    step (bit-for-bit the group body of ``pipelined_sweep``); the host
-    loop supplies the asynchrony the paper's PS promises -- block ``b+1``'s
+    step around ``blocked_group``, the group step ``pipelined_sweep``
+    runs; the host loop supplies the asynchrony the paper's PS promises --
+    block ``b+1``'s
     tier pull (including any cold-tier H2D misses) is issued *before*
     block ``b`` samples, so the miss path hides behind the MH chain.
     Exact, not approximate: blocks own disjoint rows, so the in-flight
@@ -757,58 +742,17 @@ def make_tiered_executor(state: "lda.SamplerState", cfg: "lda.LDAConfig",
         bval[: ids.size] = True
         index.append((jnp.asarray(idx), jnp.asarray(bval)))
 
-    w_dev, d_dev = state.w, state.d
-    doc_start, doc_len = state.doc_start, state.doc_len
-
     @jax.jit
     def inner(rows, nk, ndk, z_flat, idx, bval, start, key_b):
-        # bit-for-bit the group body of pipelined_sweep (staleness 0),
-        # with the block offset a traced scalar so every block of one
-        # capacity shares a single compiled trace
-        cap = idx.shape[0]
-        with jax.named_scope("alias.tables"):
-            weights = (rows.astype(jnp.float32) + cfg.beta) / (
-                nk.astype(jnp.float32)[None, :] + cfg.V * cfg.beta)
-            table = lda.build_alias_tables(weights, cfg.use_kernels,
-                                           cfg.kernel_interpret)
-        with jax.named_scope("ps.pull"):
-            wb = jnp.take(w_dev, idx)
-            db = jnp.take(d_dev, idx)
-            z0 = jnp.take(z_flat, idx)
-            local = jnp.clip(wb - start, 0, rpb - 1)
-            nwk_rows = jnp.take(rows, local, axis=0)
-            ndk_rows = jnp.take(ndk, db, axis=0)
-            aprob = jnp.take(table.prob, local, axis=0)
-            aalias = jnp.take(table.alias, local, axis=0)
-        with jax.named_scope("mh.chain"):
-            doc_draw = lda.make_doc_draw(None, db, z_flat, doc_start,
-                                         doc_len, cfg)
-            rng = lda.draw_mh_randoms(key_b, doc_draw, cap, cfg)
-            if cfg.use_kernels:
-                from repro.kernels import ops as kops
-                z_new = kops.mh_sample(rng, z0, nwk_rows, ndk_rows, nk,
-                                       aprob, aalias, cfg,
-                                       interpret=cfg.kernel_interpret)
-            else:
-                z_new = lda.mh_chain(rng, z0, nwk_rows, ndk_rows, nk, aprob,
-                                     aalias, cfg)
-            z_new = jnp.where(bval, z_new, z0)
+        # the blocked schedule's group step at staleness 0, with the block
+        # offset a traced scalar so every block of one capacity shares a
+        # single compiled trace
+        rows, nk, ndk, z_flat, local, amt = blocked_group(
+            rows, nk, ndk, z_flat, idx, bval, start, key_b, state=state,
+            layout=layout, n_rows=rpb, cfg=cfg, route=route)
         with jax.named_scope("ps.push"):
-            changed = (z_new != z0) & bval
-            d_rows = route.block_delta(
-                ps.Reassign(rows=local, words=wb, z_old=z0, z_new=z_new,
-                            changed=changed),
-                rpb, cfg.K, use_kernels=cfg.use_kernels,
-                interpret=cfg.kernel_interpret)
-            amt = changed.astype(jnp.int32)
-            nk2 = nk + (jnp.zeros((cfg.K,), jnp.int32)
-                        .at[z0].add(-amt).at[z_new].add(amt))
             rtraf = jnp.zeros((rpb,), jnp.int32).at[local].add(amt)
-            rows2 = rows + d_rows
-        with jax.named_scope("ndk.merge"):
-            ndk2 = ndk.at[db, z0].add(-amt).at[db, z_new].add(amt)
-            z2 = z_flat.at[idx].add(jnp.where(bval, z_new - z0, 0))
-        return rows2, nk2, ndk2, z2, rtraf
+        return rows, nk, ndk, z_flat, rtraf
 
     sweep_count = [0]
 
@@ -824,11 +768,11 @@ def make_tiered_executor(state: "lda.SamplerState", cfg: "lda.LDAConfig",
             if index[b] is None:
                 continue
             idx, bval = index[b]
-            rows2, nk, ndk, z, rtraf = inner(
+            rows, nk, ndk, z, rtraf = inner(
                 rows, nk, ndk, z, idx, bval,
                 jnp.asarray(b * rpb, jnp.int32), keys[b])
             rtraf_np = np.asarray(rtraf)
-            tier_h.store_block(b, rows2, rpb, row_changed=rtraf_np > 0)
+            tier_h.store_block(b, rows, rpb, row_changed=rtraf_np > 0)
             tier_h.note_traffic(b, rpb, rtraf_np)
         sweep_count[0] += 1
         if refresh_every > 0 and sweep_count[0] % refresh_every == 0:

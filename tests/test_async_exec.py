@@ -20,6 +20,7 @@ import numpy as np
 import pytest
 
 from conftest import make_lda_state
+from repro import ps
 from repro.core import lightlda as lda
 from repro.data import corpus as corpus_mod
 from repro.sharding.mesh import make_mesh
@@ -81,7 +82,7 @@ class TestStalenessZeroBitwise:
             s_, k, cfg, idx, bval, rpb))(state, key)
         got = jax.jit(lambda s_, k: async_exec.pipelined_sweep(
             s_, k, cfg, idx, bval, rpb, staleness=0,
-            hot_words=hot_words))(state, key)
+            route=ps.route_for(hot_words, cfg.V)))(state, key)
         assert bool((ref.z == got.z).all())
         assert bool((ref.nwk.value == got.nwk.value).all())
         assert bool((ref.nk.value == got.nk.value).all())
@@ -104,12 +105,39 @@ class TestStalenessZeroBitwise:
         idx, bval, rpb = _block_index(state, cfg, n_blocks=6)
         key = jax.random.PRNGKey(13)
         outs = [async_exec.pipelined_sweep(state, key, cfg, idx, bval, rpb,
-                                           staleness=2, hot_words=h)
+                                           staleness=2,
+                                           route=ps.route_for(h, cfg.V))
                 for h in (None, 0, 1, 150, cfg.V)]
         for other in outs[1:]:
             assert bool((outs[0].z == other.z).all())
             assert bool((outs[0].nwk.value == other.nwk.value).all())
             assert bool((outs[0].ndk == other.ndk).all())
+
+    def test_tiered_step_is_blocked_step(self, lda_state, tmp_path):
+        """The tiered executor's per-block step is the blocked executor's
+        group step: one model block, the pipelined index pinned to the
+        tiered capacity, and one sweep of each agrees bitwise."""
+        corp, cfg, state = lda_state(seed=8, num_shards=1)
+        route = ps.route_for(37, cfg.V)
+        tiered = state._replace(nwk=ps.tiered_matrix_from_dense(
+            state.nwk.to_dense(), 16, str(tmp_path / "tier"), route=route))
+        step, info = async_exec.make_tiered_executor(
+            tiered, cfg, async_exec.ExecConfig(model_blocks=1, route=route),
+            refresh_every=0)
+        (cap,) = info["token_caps"]
+        layout = state.nwk.layout
+        idx, bval = lda.block_token_index(
+            np.asarray(state.w), np.asarray(state.valid), layout.pad_rows,
+            layout, cap=cap)
+        key = jax.random.PRNGKey(19)
+        ref = async_exec.pipelined_sweep(
+            state, key, cfg, jnp.asarray(idx), jnp.asarray(bval),
+            layout.pad_rows, staleness=0, route=route)
+        got = step(tiered, key)
+        assert bool((ref.z == got.z).all())
+        assert bool((ref.nwk.to_dense() == got.nwk.to_dense()).all())
+        assert bool((ref.nk.value == got.nk.value).all())
+        assert bool((ref.ndk == got.ndk).all())
 
 
 class TestConservation:
@@ -124,7 +152,7 @@ class TestConservation:
             key, sub = jax.random.split(key)
             state = jax.jit(lambda s_, k: async_exec.pipelined_sweep(
                 s_, k, cfg, idx, bval, rpb, staleness=staleness,
-                hot_words=hot_words))(state, sub)
+                route=ps.route_for(hot_words, cfg.V)))(state, sub)
             _assert_conserved(state, cfg, corp.num_tokens)
 
     @pytest.mark.parametrize("staleness,hot_words", [
@@ -136,8 +164,8 @@ class TestConservation:
         for i in range(2):
             key, sub = jax.random.split(key)
             state = jax.jit(lambda s_, k: lda.sweep(
-                s_, k, cfg, staleness=staleness, hot_words=hot_words))(
-                state, sub)
+                s_, k, cfg, staleness=staleness,
+                route=ps.route_for(hot_words, cfg.V)))(state, sub)
             _assert_conserved(state, cfg, corp.num_tokens)
 
     def test_staleness_converges_like_sync(self, lda_state):
@@ -153,7 +181,7 @@ class TestConservation:
             st, key = state, jax.random.PRNGKey(21)
             step = jax.jit(lambda s_, k: async_exec.pipelined_sweep(
                 s_, k, cfg, idx, bval, rpb, staleness=staleness,
-                hot_words=64))
+                route=ps.HybridRoute(hot_words=64)))
             for _ in range(20):
                 key, sub = jax.random.split(key)
                 st = step(st, sub)
@@ -182,7 +210,7 @@ class TestKernelPathEquality:
             idx, bval, rpb = _block_index(state, cfg, n_blocks=4)
             outs[uk] = async_exec.pipelined_sweep(
                 state, jax.random.PRNGKey(17), cfg, idx, bval, rpb,
-                staleness=1, hot_words=80)
+                staleness=1, route=ps.HybridRoute(hot_words=80))
         assert bool((outs[False].z == outs[True].z).all())
         assert bool((outs[False].nwk.value == outs[True].nwk.value).all())
         assert bool((outs[False].ndk == outs[True].ndk).all())
@@ -224,7 +252,6 @@ class TestDistributedExecutor:
     matrix entry; skipped on plain single-device hosts."""
 
     def test_spmd_sweep_with_staleness_conserves(self):
-        from repro import ps
         from repro.launch import lda as launch_lda
 
         model = 2
@@ -241,7 +268,7 @@ class TestDistributedExecutor:
         w, valid = st.w, st.valid
 
         sweep_fn = jax.jit(launch_lda.make_spmd_sweep(
-            mesh, cfg, staleness=1, hot_words=32))
+            mesh, cfg, staleness=1, route=ps.HybridRoute(hot_words=32)))
         keys = jax.random.split(jax.random.PRNGKey(1), workers)
         z2, ndk2, nwk_val2, nk2 = sweep_fn(*st, keys)
         n_tokens = int(valid.sum())
@@ -285,7 +312,7 @@ if HAVE_HYPOTHESIS:
         hot_words = int(hot_frac * cfg.V)
         state = async_exec.pipelined_sweep(
             state, jax.random.PRNGKey(seed + 1), cfg, idx, bval, rpb,
-            staleness=staleness, hot_words=hot_words)
+            staleness=staleness, route=ps.route_for(hot_words, cfg.V))
         _assert_conserved(state, cfg, corp.num_tokens)
 
     @given(seed=st.integers(0, 10_000), staleness=st.integers(0, 6))

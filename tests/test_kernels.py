@@ -131,18 +131,17 @@ class TestHybridDeltaParity:
     @pytest.mark.parametrize("use_kernel", [False, True])
     @pytest.mark.parametrize("hot", [0, 1, 64, 199, 200])  # 0=all-cold, V=all-hot
     def test_matches_dense_oracle(self, hot, use_kernel):
-        from repro.core import lightlda as lda_mod
-        from repro.train.async_exec import hybrid_count_deltas
+        from repro import ps
+        from repro.train.async_exec import topic_delta
 
         v, k, b = 200, 12, 512
-        cfg = lda_mod.LDAConfig(num_topics=k, vocab_size=v)
         w, zo, zn, chg = self._batch(b, v, k, seed=hot + 1,
                                      include_boundary=max(hot, 1))
-        d = jnp.zeros((b,), jnp.int32)
-        valid = jnp.ones((b,), bool)
         ref = kref.delta_push_ref(w, zo, zn, chg, v, k)
-        d_nwk, d_nk, d_ndk = hybrid_count_deltas(
-            w, d, zo, zn, valid, 1, hot, cfg, use_kernel=use_kernel)
+        d_nwk = ps.route_for(hot, v).block_delta(
+            ps.Reassign(w, w, zo, zn, chg), v, k, use_kernels=use_kernel,
+            prefix_rows=True)
+        d_nk = topic_delta(zo, zn, chg.astype(jnp.int32), k)
         np.testing.assert_array_equal(np.asarray(ref), np.asarray(d_nwk))
         # the split must also conserve: every changed token moves one count
         assert int(np.asarray(d_nwk).sum()) == 0
